@@ -89,8 +89,8 @@ class ModelConfig:
     frontend: str = "none"           # none | patch_stub | frame_stub
     frontend_len: int = 0            # positions supplied as precomputed embeds
 
-    # --- numerics ---
-    param_dtype: str = "float32"
+    # --- numerics (training keeps float32 masters; serving casts weights
+    # to compute_dtype) ---
     compute_dtype: str = "bfloat16"
 
     # --- schedule (minicpm WSD) ---

@@ -40,13 +40,13 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_out, l_out,
     cols = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(cols >= len_ref[0], NEG_INF, s)        # (G, bk)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    p = jnp.exp(s - m_new[:, None])
+    m_prev = m_ref[...]                                  # (G, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1)
+    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
     m_ref[...] = m_new
-    acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
+    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
         p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
@@ -91,18 +91,18 @@ def flash_decode(q, k_cache, v_cache, cache_len, *,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, G, D), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, G), lambda b, h, j: (b, h, 0)),
-            pl.BlockSpec((1, 1, G), lambda b, h, j: (b, h, 0)),
+            pl.BlockSpec((1, 1, G, 1), lambda b, h, j: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, G, 1), lambda b, h, j: (b, h, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, Hkv, G, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, G), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, G), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, G, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, G, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((G, D), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -111,5 +111,5 @@ def flash_decode(q, k_cache, v_cache, cache_len, *,
 
     if return_partials:
         return (acc.reshape(B, H, D), m.reshape(B, H), l.reshape(B, H))
-    o = acc / jnp.maximum(l, 1e-30)[..., None]
+    o = acc / jnp.maximum(l, 1e-30)
     return o.reshape(B, H, D).astype(q.dtype)

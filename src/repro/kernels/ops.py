@@ -1,12 +1,12 @@
-"""jit'd public wrappers around the Pallas kernels with CPU dispatch.
+"""jit'd public wrappers around the Pallas kernels.
 
-On the TPU target the Pallas kernels run natively; on the CPU host (this
-container, and the multi-pod dry-run) `mode` selects:
-  - "interpret": execute the kernel body in the Pallas interpreter
-    (correctness tests),
+`mode` selects the implementation:
+  - None / "pallas": the compiled Pallas kernel; needs a TPU backend, and
+    ``mode=None`` anywhere else is an error rather than a silent fallback,
+  - "interpret": the kernel body in the Pallas interpreter (CPU tests),
   - "reference": the pure-XLA online-softmax path with identical math
     (dry-run lowering; Pallas TPU kernels don't lower for the CPU backend).
-Block sizes default to the SimFA-TPU autotuner's choice.
+Block sizes are fixed defaults; the SimFA-TPU autotuner does not set them.
 """
 from __future__ import annotations
 
@@ -20,15 +20,21 @@ from repro.kernels import flash_decode as _fd
 from repro.models import attention as _attn
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _resolve(mode: Optional[str]) -> str:
+    if mode is not None:
+        return mode
+    backend = jax.default_backend()
+    if backend != "tpu":
+        raise ValueError(
+            f"mode=None runs the Pallas kernel, which needs a TPU (backend is "
+            f"{backend!r}); pass mode='interpret' or mode='reference'")
+    return "pallas"
 
 
 def mha_forward(q, k, v, *, causal: bool = True, block_q: int = 128,
                 block_k: int = 128, mode: Optional[str] = None):
     """Layout: q (B, L, H, D); k/v (B, S, Hkv, D) — model-side layout."""
-    if mode is None:
-        mode = "pallas" if _on_tpu() else "reference"
+    mode = _resolve(mode)
     if mode == "reference":
         return _attn.flash_ref(q, k, v, causal=causal, chunk=block_k)
     qt = q.transpose(0, 2, 1, 3)
@@ -42,8 +48,7 @@ def mha_forward(q, k, v, *, causal: bool = True, block_q: int = 128,
 def decode_forward(q, k_cache, v_cache, cache_len, *, block_k: int = 512,
                    mode: Optional[str] = None, return_partials: bool = False):
     """Layout: q (B, 1, H, D); caches (B, S, Hkv, D) — model-side layout."""
-    if mode is None:
-        mode = "pallas" if _on_tpu() else "reference"
+    mode = _resolve(mode)
     B, L, H, D = q.shape
     if mode == "reference":
         if return_partials:

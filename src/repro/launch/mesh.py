@@ -6,17 +6,13 @@ never touches jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def _mesh_kwargs(n_axes: int) -> dict:
-    """Version-compat shim: ``jax.sharding.AxisType`` (and the
-    ``axis_types=`` kwarg of ``jax.make_mesh``) only exist on newer jax;
-    older releases treat every axis as Auto already, so the kwarg is simply
-    omitted there."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+def _auto(n_axes: int) -> tuple:
+    """Every axis Auto: the compiler propagates shardings through the model
+    (the default, Explicit, makes sharding part of each op's type)."""
+    return (AxisType.Auto,) * n_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -24,13 +20,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod:  2x16x16 = 512 chips (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
-def make_mesh(shape, axes):
+def make_mesh(shape, axes, *, devices=None):
     """Arbitrary mesh (tests / local runs)."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_mesh_kwargs(len(axes)))
+                         axis_types=_auto(len(axes)), devices=devices)
 
 
 def make_local_mesh(model: int = 1):

@@ -79,6 +79,7 @@ class ServeEngine:
         from repro.serve.decode import make_serve_step
         self._decode = jax.jit(make_serve_step(cfg))
         self.steps = 0
+        self.finished: List[Request] = []
         self.prompt_len: Optional[int] = None
 
     def submit(self, req: Request):
@@ -92,10 +93,13 @@ class ServeEngine:
 
     # --------------------------------------------------------------
     def _prefill_slot(self, slot: int, req: Request):
-        """Single-request prefill into the shared cache (slot-batched)."""
+        """Single-request prefill into the shared cache (slot-batched). The
+        prompt's last position gives the request's first token."""
         toks = jnp.asarray(req.prompt, jnp.int32)[None]
-        _, cache1 = api.prefill(self.cfg, self.params, {"tokens": toks},
-                                max_seq=self.max_seq)
+        hidden, cache1 = api.prefill(self.cfg, self.params, {"tokens": toks},
+                                     max_seq=self.max_seq)
+        logits = api.unembed(self.cfg, self.params, hidden[:, -1])
+        first = int(jnp.argmax(logits[0]))
         slots = self.slots
 
         def splice(big, small):
@@ -112,15 +116,23 @@ class ServeEngine:
 
         self.cache = jax.tree.map(splice, self.cache, cache1)
         self.cache["idx"] = cache1["idx"]
-        self.tokens = self.tokens.at[slot, 0].set(int(req.prompt[-1]))
+        self.tokens = self.tokens.at[slot, 0].set(first)
+        self._emit(slot, req, first)
+
+    def _emit(self, slot: int, req: Request, tok: int):
+        req.out.append(tok)
+        if len(req.out) >= req.max_new:
+            req.done = True
+            self.active[slot] = None
+            self.finished.append(req)
 
     def step(self):
         """One engine tick: refill empty slots, run one decode step."""
         for i in range(self.slots):
             if self.active[i] is None and self.queue:
                 req = self.queue.pop(0)
-                self._prefill_slot(i, req)
                 self.active[i] = req
+                self._prefill_slot(i, req)
         if all(r is None for r in self.active):
             return False
         t0 = time.time()
@@ -131,16 +143,14 @@ class ServeEngine:
         self.steps += 1
         toks = np.asarray(next_tok)[:, 0]
         for i, req in enumerate(self.active):
-            if req is None:
-                continue
-            req.out.append(int(toks[i]))
-            if len(req.out) >= req.max_new:
-                req.done = True
-                self.active[i] = None
+            if req is not None:
+                self._emit(i, req, int(toks[i]))
         return True
 
     def run(self, max_steps: int = 10_000) -> List[Request]:
-        finished = []
+        """Serve until the queue drains; returns the requests finished since
+        the last ``run()``, in the order they finished."""
         while (self.queue or any(self.active)) and self.steps < max_steps:
             self.step()
-        return finished
+        done, self.finished = self.finished, []
+        return done

@@ -36,13 +36,8 @@ COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
 
 
 def xla_cost_analysis(compiled) -> dict:
-    """Normalize ``compiled.cost_analysis()`` across jax versions: older
-    releases return a one-element list of dicts (per partition), newer ones
-    return the dict directly."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
+    """XLA's own cost analysis of ``compiled`` (a dict of named counters)."""
+    return dict(compiled.cost_analysis())
 
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
 _COMP_RE = re.compile(r"^\s*%?([\w\.\-]+)\s*(?:\([^)]*\))?\s*->", re.M)

@@ -193,8 +193,9 @@ def test_serve_engine_batched_requests():
                     max_new=3) for i in range(5)]
     for r in reqs:
         eng.submit(r)
-    eng.run(max_steps=200)
-    assert all(len(r.out) == 3 for r in reqs)
+    finished = eng.run(max_steps=200)
+    assert sorted(r.rid for r in finished) == [r.rid for r in reqs]
+    assert all(len(r.out) == 3 and r.done for r in reqs)
     assert eng.steps < 200
 
 

@@ -154,3 +154,16 @@ def test_flash_decode_property(S, clen, seed):
     o_ref = ref.flash_decode_ref(q, kc, vc, jnp.full((1,), clen))
     np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
                                atol=3e-5, rtol=3e-5)
+
+
+@pytest.mark.parametrize("fn", ["mha_forward", "decode_forward"])
+def test_ops_default_mode_needs_tpu(fn):
+    """mode=None means the compiled kernel; off the TPU that is an error,
+    never a quiet switch to the reference."""
+    if jax.default_backend() == "tpu":
+        pytest.skip("runs the kernel on a TPU")
+    q = jnp.zeros((1, 1, 4, 64))
+    kv = jnp.zeros((1, 128, 2, 64))
+    args = (q, kv, kv) if fn == "mha_forward" else (q, kv, kv, 1)
+    with pytest.raises(ValueError, match="needs a TPU"):
+        getattr(ops, fn)(*args)

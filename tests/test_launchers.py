@@ -36,7 +36,8 @@ def test_train_launcher(tmp_path):
 
 
 def test_serve_launcher():
-    r = _run(["repro.launch.serve", "--arch", "qwen2.5-3b", "--requests",
+    r = _run(["repro.launch.serve", "--arch", "qwen2.5-3b", "--reduced",
+              "--requests",
               "4", "--slots", "2", "--max-new", "3", "--prompt-len", "8",
               "--max-seq", "32"])
     assert r.returncode == 0, r.stderr[-2000:]

@@ -101,17 +101,18 @@ def test_elastic_reshard_restore(tmp_path):
         import sys, numpy as np, jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.ckpt.manager import CheckpointManager
+        from repro.launch.mesh import make_mesh
 
         out = sys.argv[1]
         w = jnp.arange(64 * 32, dtype=jnp.float32).reshape(64, 32)
 
-        mesh_a = jax.make_mesh((2, 4), ("data", "model"))
+        mesh_a = make_mesh((2, 4), ("data", "model"))
         wa = jax.device_put(w, NamedSharding(mesh_a, P("data", "model")))
         mgr = CheckpointManager(out, async_save=False)
         mgr.save(1, {"w": wa})
 
         # restore onto a re-shaped mesh (4x2) with transposed layout
-        mesh_b = jax.make_mesh((4, 2), ("data", "model"))
+        mesh_b = make_mesh((4, 2), ("data", "model"))
         sh_b = {"w": NamedSharding(mesh_b, P("model", "data"))}
         restored = mgr.restore(1, {"w": wa}, shardings=sh_b)
         np.testing.assert_array_equal(np.asarray(restored["w"]), np.asarray(w))
