@@ -99,6 +99,13 @@ def serve(args) -> Served:
     print(f"served {len(finished)} requests / {toks} tokens in "
           f"{eng.steps} steps, {dt:.2f}s; "
           f"{eng.straggler.slow_steps} straggler step(s)")
+    if finished:
+        wait = [r.t_admit - r.t_queued for r in finished]
+        first = [r.t_first - r.t_queued for r in finished]
+        print("queue wait p50/p95 {:.1f}/{:.1f} ms; first token p50/p95 "
+              "{:.1f}/{:.1f} ms".format(
+                  *(1e3 * np.percentile(xs, q) for xs in (wait, first)
+                    for q in (50, 95))))
     return Served(cfg, params, eng, finished, dt)
 
 

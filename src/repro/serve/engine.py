@@ -5,6 +5,19 @@ predictor (the paper's model as a production feature — DESIGN.md §4).
 Slots hold independent requests; finished slots are refilled from the queue
 without stopping the decode loop. Designed so the decode step is the same
 function the dry-run lowers for the decode_32k/long_500k cells.
+
+Each phase of ``step()`` is a host span in the profiler's trace, recorded
+only while a profile is being taken (``jax.profiler.start_trace``):
+
+- ``serve.step``: the whole of ``step()``; its time outside its children
+  is the slot loop and other bookkeeping.
+- ``serve.admit`` (stats ``rid``, ``slot``): one request's admission, made
+  of ``serve.prefill`` (prefill and unembed, tracing and lowering
+  included), ``serve.first_token`` (the wait for its first token) and
+  ``serve.splice`` (its cache and token into the slot).
+- ``serve.decode``: the call of the jitted decode step (dispatch);
+  ``serve.decode_wait``: the wait for it; ``serve.fetch``: the new tokens
+  copied to the host.
 """
 from __future__ import annotations
 
@@ -15,6 +28,7 @@ from typing import List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models import api
 
@@ -26,6 +40,11 @@ class Request:
     max_new: int = 16
     out: List[int] = field(default_factory=list)
     done: bool = False
+    # time.perf_counter() when the engine queued the request, began its
+    # prefill and gave its first token; None until then
+    t_queued: Optional[float] = None
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
 
 
 @dataclass
@@ -89,17 +108,14 @@ class ServeEngine:
             self.prompt_len = len(req.prompt)
         assert len(req.prompt) == self.prompt_len, \
             "engine instance serves fixed-length prompts"
+        req.t_queued = time.perf_counter()
         self.queue.append(req)
 
     # --------------------------------------------------------------
     def _prefill_slot(self, slot: int, req: Request):
         """Single-request prefill into the shared cache (slot-batched). The
         prompt's last position gives the request's first token."""
-        toks = jnp.asarray(req.prompt, jnp.int32)[None]
-        hidden, cache1 = api.prefill(self.cfg, self.params, {"tokens": toks},
-                                     max_seq=self.max_seq)
-        logits = api.unembed(self.cfg, self.params, hidden[:, -1])
-        first = int(jnp.argmax(logits[0]))
+        req.t_admit = time.perf_counter()
         slots = self.slots
 
         def splice(big, small):
@@ -114,12 +130,24 @@ class ServeEngine:
                     return big.at[tuple(sl)].set(small.astype(big.dtype))
             return big
 
-        self.cache = jax.tree.map(splice, self.cache, cache1)
-        self.cache["idx"] = cache1["idx"]
-        self.tokens = self.tokens.at[slot, 0].set(first)
-        self._emit(slot, req, first)
+        with TraceAnnotation("serve.admit", rid=req.rid, slot=slot):
+            with TraceAnnotation("serve.prefill"):
+                toks = jnp.asarray(req.prompt, jnp.int32)[None]
+                hidden, cache1 = api.prefill(self.cfg, self.params,
+                                             {"tokens": toks},
+                                             max_seq=self.max_seq)
+                logits = api.unembed(self.cfg, self.params, hidden[:, -1])
+            with TraceAnnotation("serve.first_token"):
+                first = int(jnp.argmax(logits[0]))
+            with TraceAnnotation("serve.splice"):
+                self.cache = jax.tree.map(splice, self.cache, cache1)
+                self.cache["idx"] = cache1["idx"]
+                self.tokens = self.tokens.at[slot, 0].set(first)
+            self._emit(slot, req, first)
 
     def _emit(self, slot: int, req: Request, tok: int):
+        if not req.out:
+            req.t_first = time.perf_counter()
         req.out.append(tok)
         if len(req.out) >= req.max_new:
             req.done = True
@@ -128,24 +156,29 @@ class ServeEngine:
 
     def step(self):
         """One engine tick: refill empty slots, run one decode step."""
-        for i in range(self.slots):
-            if self.active[i] is None and self.queue:
-                req = self.queue.pop(0)
-                self.active[i] = req
-                self._prefill_slot(i, req)
-        if all(r is None for r in self.active):
-            return False
-        t0 = time.time()
-        next_tok, self.cache = self._decode(self.params, self.cache, self.tokens)
-        next_tok.block_until_ready()
-        self.straggler.observe(time.time() - t0)
-        self.tokens = next_tok
-        self.steps += 1
-        toks = np.asarray(next_tok)[:, 0]
-        for i, req in enumerate(self.active):
-            if req is not None:
-                self._emit(i, req, int(toks[i]))
-        return True
+        with TraceAnnotation("serve.step"):
+            for i in range(self.slots):
+                if self.active[i] is None and self.queue:
+                    req = self.queue.pop(0)
+                    self.active[i] = req
+                    self._prefill_slot(i, req)
+            if all(r is None for r in self.active):
+                return False
+            with TraceAnnotation("serve.decode"):
+                next_tok, self.cache = self._decode(self.params, self.cache,
+                                                    self.tokens)
+            t0 = time.perf_counter()
+            with TraceAnnotation("serve.decode_wait"):
+                next_tok.block_until_ready()
+            self.straggler.observe(time.perf_counter() - t0)
+            self.tokens = next_tok
+            self.steps += 1
+            with TraceAnnotation("serve.fetch"):
+                toks = np.asarray(next_tok)[:, 0]
+            for i, req in enumerate(self.active):
+                if req is not None:
+                    self._emit(i, req, int(toks[i]))
+            return True
 
     def run(self, max_steps: int = 10_000) -> List[Request]:
         """Serve until the queue drains; returns the requests finished since
