@@ -103,9 +103,10 @@ def serve(args) -> Served:
         wait = [r.t_admit - r.t_queued for r in finished]
         first = [r.t_first - r.t_queued for r in finished]
         print("queue wait p50/p95 {:.1f}/{:.1f} ms; first token p50/p95 "
-              "{:.1f}/{:.1f} ms".format(
+              "{:.1f}/{:.1f} ms; decode step aliases {} B, temp {} B".format(
                   *(1e3 * np.percentile(xs, q) for xs in (wait, first)
-                    for q in (50, 95))))
+                    for q in (50, 95)),
+                  eng.decode_alias_bytes, eng.decode_temp_bytes))
     return Served(cfg, params, eng, finished, dt)
 
 

@@ -165,12 +165,15 @@ def attn_init(key, cfg):
 
 
 def attn_apply(p, x, cfg, *, positions, kv_cache=None, cache_index=None,
-               cross_kv=None, attn_fn=None, use_rope=True):
+               cache_layer=None, cross_kv=None, attn_fn=None, use_rope=True):
     """Returns (out, new_kv) where new_kv is (k, v) of this call's tokens.
 
     kv_cache: optional (k_cache, v_cache) of shape (B, S_max, Hkv, D) --
-    decode path (x is (B,1,d)). cross_kv: precomputed (k, v) for
-    cross-attention (no rope, no cache write).
+    decode path (x is (B,1,d)); returned with this call's tokens written
+    at ``cache_index``. With ``cache_layer`` the caches are every layer's,
+    (L, B, S_max, Hkv, D): only layer ``cache_layer``'s new positions are
+    written, and that layer is read where it is stored. cross_kv:
+    precomputed (k, v) for cross-attention (no rope, no cache write).
     """
     B, L, _ = x.shape
     H, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -192,9 +195,17 @@ def attn_apply(p, x, cfg, *, positions, kv_cache=None, cache_index=None,
     if kv_cache is not None:
         k_cache, v_cache = kv_cache
         idx = cache_index
-        k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, k.astype(k_cache.dtype), idx, axis=1)
-        v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, v.astype(v_cache.dtype), idx, axis=1)
-        o = decode_attend(q, k_cache, v_cache, idx + L)
+        if cache_layer is None:
+            k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, k.astype(k_cache.dtype), idx, axis=1)
+            v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, v.astype(v_cache.dtype), idx, axis=1)
+            k_att, v_att = k_cache, v_cache
+        else:
+            at = (cache_layer, 0, idx, 0, 0)
+            k_cache = jax.lax.dynamic_update_slice(k_cache, k[None].astype(k_cache.dtype), at)
+            v_cache = jax.lax.dynamic_update_slice(v_cache, v[None].astype(v_cache.dtype), at)
+            k_att = jax.lax.dynamic_index_in_dim(k_cache, cache_layer, keepdims=False)
+            v_att = jax.lax.dynamic_index_in_dim(v_cache, cache_layer, keepdims=False)
+        o = decode_attend(q, k_att, v_att, idx + L)
         out = layers.dense(p["wo"], o.reshape(B, L, H * hd), dtype=dt)
         return out, (k_cache, v_cache)
 

@@ -49,11 +49,11 @@ def _block_init(key, cfg):
 
 
 def _block_apply(p, x, cfg, *, positions, kv=None, cache_index=None,
-                 attn_fn=None, moe_impl="einsum"):
+                 cache_layer=None, attn_fn=None, moe_impl="einsum"):
     h = layers.apply_norm(cfg.norm, p["attn_norm"], x)
     h, new_kv = attention.attn_apply(
         p["attn"], h, cfg, positions=positions, kv_cache=kv,
-        cache_index=cache_index, attn_fn=attn_fn)
+        cache_index=cache_index, cache_layer=cache_layer, attn_fn=attn_fn)
     x = x + h
     h = layers.apply_norm(cfg.norm, p["mlp_norm"], x)
     if cfg.family == "moe":
@@ -256,12 +256,17 @@ def decode_lm(cfg, params, cache, tokens):
     positions = idx + jnp.zeros((1, 1), jnp.int32)
 
     if cfg.family in ("dense", "moe", "vlm"):
-        def body(h, blk_kv):
-            blk, k, v = blk_kv
-            h, (k2, v2), _ = _block_apply(blk, h, cfg, positions=positions,
-                                          kv=(k, v), cache_index=idx)
-            return pctx.constrain(h, "residual_dec"), (k2, v2)
-        x, (ks, vs) = jax.lax.scan(body, x, (params["blocks"], cache["k"], cache["v"]))
+        # the stacked cache rides in the carry, so each layer writes its one
+        # new position in place; scanned as xs/ys, every layer's K/V would
+        # be sliced out and written back whole
+        def body(carry, blk):
+            h, k, v, layer = carry
+            h, (k, v), _ = _block_apply(blk, h, cfg, positions=positions,
+                                        kv=(k, v), cache_index=idx,
+                                        cache_layer=layer)
+            return (pctx.constrain(h, "residual_dec"), k, v, layer + 1), None
+        (x, ks, vs, _), _ = jax.lax.scan(
+            body, (x, cache["k"], cache["v"], jnp.int32(0)), params["blocks"])
         new_cache = dict(cache, k=ks, v=vs, idx=idx + 1)
     elif cfg.family == "ssm":
         x = layers.apply_norm("layernorm", params["ln0"], x)

@@ -4,7 +4,11 @@ predictor (the paper's model as a production feature — DESIGN.md §4).
 
 Slots hold independent requests; finished slots are refilled from the queue
 without stopping the decode loop. Designed so the decode step is the same
-function the dry-run lowers for the decode_32k/long_500k cells.
+function the dry-run lowers for the decode_32k/long_500k cells. The engine
+compiles it once, with the cache donated and its axes stored in the order
+of the layout the compiler chose (``serve.decode.compile_serve_step``);
+the cache is made, and each admission spliced into it, in that order and
+in place.
 
 Each phase of ``step()`` is a host span in the profiler's trace, recorded
 only while a profile is being taken (``jax.profiler.start_trace``):
@@ -31,6 +35,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.models import api
+from repro.serve.decode import cache_axes, compile_serve_step, store
 
 
 @dataclass
@@ -82,6 +87,25 @@ class StragglerPolicy:
         return cls(expected_step_s=med, factor=factor)
 
 
+def _make_splice(slots: int, axes):
+    def splice(cache, one, slot):
+        """``one``, a single request's cache, into batch entry ``slot`` of
+        ``cache`` (``slots`` entries, stored in the order ``axes`` gives);
+        its scalar index replaces the cache's."""
+        def put(big, small):
+            if small.ndim == 0:
+                return small
+            for ax in range(big.ndim):
+                if (big.shape[ax] == slots and small.shape[ax] == 1
+                        and big.shape[:ax] == small.shape[:ax]
+                        and big.shape[ax + 1:] == small.shape[ax + 1:]):
+                    return jax.lax.dynamic_update_slice_in_dim(
+                        big, small.astype(big.dtype), slot, axis=ax)
+            return big
+        return jax.tree.map(put, cache, store(one, axes))
+    return splice
+
+
 class ServeEngine:
     def __init__(self, cfg, params, *, slots: int = 4, max_seq: int = 256,
                  straggler: Optional[StragglerPolicy] = None):
@@ -92,11 +116,23 @@ class ServeEngine:
         self.straggler = straggler or StragglerPolicy()
         self.queue: List[Request] = []
         self.active: List[Optional[Request]] = [None] * slots
-        self.cache = api.init_cache(cfg, slots, max_seq,
-                                    dtype=jnp.dtype(cfg.compute_dtype))
         self.tokens = jnp.zeros((slots, 1), jnp.int32)
-        from repro.serve.decode import make_serve_step
-        self._decode = jax.jit(make_serve_step(cfg))
+        shape = dict(batch=slots, max_seq=max_seq,
+                     sharding=self.tokens.sharding)
+        self.cache_axes = cache_axes(cfg, params, **shape)
+        self._decode = compile_serve_step(cfg, params, self.cache_axes,
+                                          **shape)
+        self.cache = jax.jit(lambda: store(api.init_cache(
+            cfg, slots, max_seq, dtype=jnp.dtype(cfg.compute_dtype)),
+            self.cache_axes))()
+        self._splice = jax.jit(_make_splice(slots, self.cache_axes),
+                               donate_argnums=(0,))
+        mem = self._decode.memory_analysis()
+        # bytes the step writes into its donated inputs, and its scratch:
+        # the cache's size and under one layer's K when it is updated in
+        # place; None where the backend does not say
+        self.decode_alias_bytes = getattr(mem, "alias_size_in_bytes", None)
+        self.decode_temp_bytes = getattr(mem, "temp_size_in_bytes", None)
         self.steps = 0
         self.finished: List[Request] = []
         self.prompt_len: Optional[int] = None
@@ -116,20 +152,6 @@ class ServeEngine:
         """Single-request prefill into the shared cache (slot-batched). The
         prompt's last position gives the request's first token."""
         req.t_admit = time.perf_counter()
-        slots = self.slots
-
-        def splice(big, small):
-            if small.ndim == 0:
-                return big            # scalar index: set below
-            for ax in range(big.ndim):
-                if (big.shape[ax] == slots and small.shape[ax] == 1
-                        and big.shape[:ax] == small.shape[:ax]
-                        and big.shape[ax + 1:] == small.shape[ax + 1:]):
-                    sl = [slice(None)] * big.ndim
-                    sl[ax] = slice(slot, slot + 1)
-                    return big.at[tuple(sl)].set(small.astype(big.dtype))
-            return big
-
         with TraceAnnotation("serve.admit", rid=req.rid, slot=slot):
             with TraceAnnotation("serve.prefill"):
                 toks = jnp.asarray(req.prompt, jnp.int32)[None]
@@ -140,8 +162,7 @@ class ServeEngine:
             with TraceAnnotation("serve.first_token"):
                 first = int(jnp.argmax(logits[0]))
             with TraceAnnotation("serve.splice"):
-                self.cache = jax.tree.map(splice, self.cache, cache1)
-                self.cache["idx"] = cache1["idx"]
+                self.cache = self._splice(self.cache, cache1, slot)
                 self.tokens = self.tokens.at[slot, 0].set(first)
             self._emit(slot, req, first)
 

@@ -1,0 +1,116 @@
+"""The serving engine's K/V cache: its decode step writes one position per
+layer into the cache it was given, and its tokens are a cache-free
+forward's greedy tokens, wave after wave of requests through the same
+slots; with the cache's axes stored in the order the compiler chose here
+(the logical one on the CPU) and in the order a v5e chooses."""
+from __future__ import annotations
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro.configs import registry
+from repro.models import api, transformer
+from repro.serve.decode import load
+from repro.serve import engine
+from repro.serve.engine import Request, ServeEngine
+
+KEY = jax.random.PRNGKey(0)
+PROMPT, MAX_SEQ = 6, 16
+
+
+# L, S, H, B, D: the order of the cache's axes in the layout the TPU
+# compiler picks for both benchmark cells' decode steps on a v5e
+V5E_AXES = (0, 2, 3, 1, 4)
+
+
+@pytest.fixture(params=["compiler", "v5e"])
+def axes(request, monkeypatch):
+    if request.param == "v5e":
+        def v5e(cfg, params, *, batch, max_seq, sharding):
+            return jax.tree.map(
+                lambda c: V5E_AXES if c.ndim else (),
+                jax.eval_shape(lambda: api.init_cache(cfg, batch, max_seq)))
+        monkeypatch.setattr(engine, "cache_axes", v5e)
+    return request.param
+
+
+def _model(arch):
+    """A float32 reduced config of ``arch`` with three layers, so that a
+    layer written one index off lands on another layer's K/V."""
+    cfg = dataclasses.replace(registry.get(arch).reduced(), num_layers=3,
+                              compute_dtype="float32")
+    return cfg, api.init(cfg, KEY)
+
+
+def _requests(cfg, n, max_new):
+    rng = np.random.default_rng(0)
+    return [Request(rid=i, prompt=rng.integers(0, cfg.vocab_size, PROMPT),
+                    max_new=max_new) for i in range(n)]
+
+
+def _logical(eng, cache):
+    """The engine's cache on the host, axes in their logical order."""
+    return jax.tree.map(np.asarray, load(cache, eng.cache_axes))
+
+
+@pytest.mark.parametrize("arch", ["olmo-1b", "qwen2.5-3b"],
+                         ids=["mha", "gqa"])
+def test_decode_step_writes_one_position_per_layer(arch, axes):
+    cfg, params = _model(arch)
+    eng = ServeEngine(cfg, params, slots=2, max_seq=MAX_SEQ)
+    for r in _requests(cfg, 2, 8):
+        eng.submit(r)
+    eng.step()                               # admits both, decodes once
+    if axes == "v5e":
+        assert eng.cache["k"].shape[1] == MAX_SEQ
+    # copies: a host view of the cache's own buffer would keep the step
+    # from taking it
+    before = _logical(eng, jax.tree.map(jnp.copy, eng.cache))
+    tokens, k_in = np.asarray(eng.tokens), eng.cache["k"]
+    eng.step()
+    assert k_in.is_deleted()                 # the step took the cache's buffer
+    after = _logical(eng, eng.cache)
+
+    idx = int(before["idx"])
+    assert int(after["idx"]) == idx + 1
+    others = np.arange(MAX_SEQ) != idx
+    for name in ("k", "v"):
+        np.testing.assert_array_equal(after[name][:, :, others],
+                                      before[name][:, :, others])
+
+    # layer by layer, un-jitted, each layer's own cache
+    x = transformer._embed_inputs(cfg, params, jnp.asarray(tokens), None)
+    positions = jnp.full((1, 1), idx, jnp.int32)
+    for layer in range(cfg.num_layers):
+        blk = jax.tree.map(lambda p: p[layer], params["blocks"])
+        x, (k, v), _ = transformer._block_apply(
+            blk, x, cfg, positions=positions, cache_index=idx,
+            kv=(before["k"][layer], before["v"][layer]))
+        np.testing.assert_allclose(after["k"][layer, :, idx], k[:, idx],
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(after["v"][layer, :, idx], v[:, idx],
+                                   rtol=1e-5, atol=1e-5)
+
+
+def test_two_waves_match_a_cache_free_forward(axes):
+    """Four requests on two slots: the second wave is spliced over the
+    first's cache, whose later positions it must not see."""
+    cfg, params = _model("qwen2.5-3b")
+    eng = ServeEngine(cfg, params, slots=2, max_seq=MAX_SEQ)
+    reqs = _requests(cfg, 4, 5)
+    for r in reqs:
+        eng.submit(r)
+    assert sorted(r.rid for r in eng.run()) == [0, 1, 2, 3]
+
+    seqs = np.stack([np.concatenate([r.prompt, r.out]) for r in reqs])
+    hidden, _ = api.forward_hidden(cfg, params, {"tokens": jnp.asarray(seqs)},
+                                   remat="none")
+    # position PROMPT-1+t predicts output token t
+    logits = api.unembed(cfg, params, hidden[:, PROMPT - 1:-1])
+    np.testing.assert_array_equal(np.argmax(np.asarray(logits), -1),
+                                  seqs[:, PROMPT:])
+
