@@ -63,9 +63,27 @@ class ModelConfig:
     bias: bool = False               # linear-layer bias (whisper: True)
 
     # --- MoE ---
-    num_experts: int = 0
+    num_experts: int = 0             # the router's outputs
     experts_per_token: int = 0
     capacity_factor: float = 1.25
+    # "capacity": one-hot dispatch that drops tokens over capacity;
+    # "dropless": the layer holds experts [first_expert, first_expert +
+    # experts_held) of the router's num_experts (0: all of them), computes
+    # every held expert's part with no token dropped, and adds the shared
+    # experts (models/moe.moe_apply_held)
+    moe_dispatch: str = "capacity"
+    experts_held: int = 0
+    first_expert: int = 0
+    moe_d_ff: int = 0                # routed expert width (0 -> d_ff)
+    num_shared_experts: int = 0      # one shared MLP of moe_d_ff x this
+    routed_scaling: float = 1.0      # routing weights' factor
+    first_dense_layers: int = 0      # leading layers with a dense MLP
+
+    # --- latent attention (MLA, DeepSeek-V2/V3); on when kv_lora_rank > 0 ---
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # --- SSM / hybrid ---
     ssm_state: int = 0
@@ -108,6 +126,12 @@ class ModelConfig:
         return self.num_heads // max(self.num_kv_heads, 1)
 
     @property
+    def reports_routing(self) -> bool:
+        """Whether the decode step reports its routed counts: an MoE whose
+        layer holds a share of the experts (``moe_dispatch`` dropless)."""
+        return self.family == "moe" and self.moe_dispatch == "dropless"
+
+    @property
     def is_attention_free(self) -> bool:
         return self.family == "ssm"
 
@@ -126,12 +150,33 @@ class ModelConfig:
         return True, ""
 
     # ------------------------------------------------------------------
-    def param_count(self) -> int:
-        """Analytic parameter count (used for 6ND roofline maths)."""
-        d, v = self.d_model, self.vocab_size
+    def _attn_params(self) -> int:
+        d, h = self.d_model, self.num_heads
+        if self.kv_lora_rank:
+            r, rope = self.kv_lora_rank, self.qk_rope_head_dim
+            return d * h * (self.qk_nope_head_dim + rope) + d * (r + rope) \
+                + r * h * (self.qk_nope_head_dim + self.v_head_dim) \
+                + h * self.v_head_dim * d
         hd = self.head_dim
-        attn = d * (self.num_heads * hd) + 2 * d * (self.num_kv_heads * hd) \
-            + (self.num_heads * hd) * d
+        return d * (h * hd) + 2 * d * (self.num_kv_heads * hd) + (h * hd) * d
+
+    @property
+    def experts_here(self) -> int:
+        """Routed experts this configuration holds in each MoE layer."""
+        return self.experts_held or self.num_experts
+
+    def _moe_params(self, experts: int) -> int:
+        """One MoE layer's matmul weights with ``experts`` routed experts:
+        those experts, the shared MLP and the router."""
+        ff = self.moe_d_ff or self.d_ff
+        return (experts + self.num_shared_experts) * 3 * self.d_model * ff \
+            + self.d_model * self.num_experts
+
+    def param_count(self) -> int:
+        """Analytic parameter count of the matmul weights and embeddings
+        (used for 6ND roofline maths); an MoE counts the experts it holds."""
+        d, v = self.d_model, self.vocab_size
+        attn = self._attn_params()
         if self.mlp in ("swiglu", "geglu"):
             mlp_dense = 3 * d * self.d_ff
         else:
@@ -139,9 +184,13 @@ class ModelConfig:
         emb = v * d * (1 if self.tie_embeddings else 2)
 
         if self.family == "moe":
-            mlp = self.num_experts * mlp_dense + d * self.num_experts  # + router
-            per_layer = attn + mlp
-            return self.num_layers * per_layer + emb
+            n_dense = self.first_dense_layers
+            if self.moe_dispatch == "dropless":
+                mlp = self._moe_params(self.experts_here)
+            else:
+                mlp = self.num_experts * mlp_dense + d * self.num_experts  # + router
+            return n_dense * (attn + mlp_dense) \
+                + (self.num_layers - n_dense) * (attn + mlp) + emb
         if self.family == "ssm":  # rwkv6
             d_in = d
             tmix = 4 * d * d_in + 6 * d * 32 * 2 + d_in  # r,k,v,o + lora-ish mixers
@@ -162,17 +211,22 @@ class ModelConfig:
         return self.num_layers * (attn + mlp_dense) + emb
 
     def active_param_count(self) -> int:
-        """Activated params per token (MoE: only routed experts)."""
+        """Activated params per token (MoE: the experts a token is routed
+        to, ``experts_per_token`` of them wherever they are held, and the
+        shared ones)."""
         if self.family != "moe":
             return self.param_count()
         d = self.d_model
-        hd = self.head_dim
-        attn = d * (self.num_heads * hd) + 2 * d * (self.num_kv_heads * hd) \
-            + (self.num_heads * hd) * d
+        attn = self._attn_params()
         mlp_dense = 3 * d * self.d_ff
-        per_layer = attn + self.experts_per_token * mlp_dense
+        n_dense = self.first_dense_layers
+        if self.moe_dispatch == "dropless":
+            mlp = self._moe_params(self.experts_per_token)
+        else:
+            mlp = self.experts_per_token * mlp_dense
         emb = self.vocab_size * d * (1 if self.tie_embeddings else 2)
-        return self.num_layers * per_layer + emb
+        return n_dense * (attn + mlp_dense) \
+            + (self.num_layers - n_dense) * (attn + mlp) + emb
 
     # ------------------------------------------------------------------
     def reduced(self) -> "ModelConfig":
@@ -190,6 +244,15 @@ class ModelConfig:
         )
         if self.family == "moe":
             kw.update(num_experts=4, experts_per_token=2)
+            if self.moe_dispatch == "dropless":
+                kw.update(num_experts=8, experts_held=4 if self.experts_held
+                          else 0, experts_per_token=3,
+                          moe_d_ff=32 if self.moe_d_ff else 0,
+                          first_dense_layers=min(self.first_dense_layers, 1),
+                          num_layers=3 if self.first_dense_layers else 2)
+        if self.kv_lora_rank:
+            kw.update(kv_lora_rank=32, qk_nope_head_dim=16,
+                      qk_rope_head_dim=8, v_head_dim=16)
         if self.family in ("hybrid",):
             kw.update(hybrid_prologue=1, hybrid_groups=1, hybrid_mamba_per_group=1,
                       ssm_state=8, num_layers=3)

@@ -7,9 +7,11 @@ from repro.configs.base import SHAPES, ModelConfig, ShapeConfig
 from repro.configs import (
     dbrx_132b, grok_1_314b, olmo_1b, command_r_plus_104b, minicpm_2b,
     qwen2_5_3b, zamba2_7b, pixtral_12b, rwkv6_7b, whisper_large_v3, llama3,
+    moonlight_16b_a3b,
 )
 
-# The 10 assigned architectures (+ the paper's own llama3-8b as an extra).
+# The 10 assigned architectures, and two extras: the paper's own llama3-8b,
+# and moonlight-16b-a3b (latent attention with routed and shared experts).
 ARCHS: Dict[str, ModelConfig] = {
     "dbrx-132b": dbrx_132b.CONFIG,
     "grok-1-314b": grok_1_314b.CONFIG,
@@ -22,9 +24,11 @@ ARCHS: Dict[str, ModelConfig] = {
     "rwkv6-7b": rwkv6_7b.CONFIG,
     "whisper-large-v3": whisper_large_v3.CONFIG,
     "llama3-8b": llama3.CONFIG,   # extra: the paper's validation family
+    "moonlight-16b-a3b": moonlight_16b_a3b.CONFIG,
 }
 
-ASSIGNED = [a for a in ARCHS if a != "llama3-8b"]
+EXTRA = ("llama3-8b", "moonlight-16b-a3b")
+ASSIGNED = [a for a in ARCHS if a not in EXTRA]
 
 
 def get(arch: str) -> ModelConfig:

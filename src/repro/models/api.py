@@ -62,6 +62,8 @@ def prefill(cfg, params, batch, *, max_seq=None, remat: str = "full",
 
 
 def decode(cfg, params, cache, tokens):
+    """(logits, cache), and the routed counts where ``cfg.reports_routing``
+    (``transformer.decode_lm``)."""
     if cfg.family == "encdec":
         return encdec.decode_encdec(cfg, params, cache, tokens)
     return transformer.decode_lm(cfg, params, cache, tokens)

@@ -44,13 +44,14 @@ def flash_ref(q, k, v, *, causal: bool, q_offset=0, chunk: int = 512,
     """Online-softmax attention scanned over KV chunks (pure jnp).
 
     Never materializes the (L, S) score matrix for more than one KV chunk;
-    this is the FlashAttention dataflow expressed at the XLA level.
+    this is the FlashAttention dataflow expressed at the XLA level. ``v``
+    may have another head size than ``q`` and ``k`` (latent attention).
     ``pv_bf16`` stores the probability tile at half width for the PV matmul
     (FA3 §5.2 does exactly this FP32->FP16 conversion before P@V) — §Perf
     knob that cuts the dominant score-tile HBM traffic.
     """
     B, L, H, D = q.shape
-    S, Hkv = k.shape[1], k.shape[2]
+    S, Hkv, Dv = k.shape[1], k.shape[2], v.shape[-1]
     G = H // Hkv
     chunk = min(chunk, S)
     n = -(-S // chunk)
@@ -59,7 +60,7 @@ def flash_ref(q, k, v, *, causal: bool, q_offset=0, chunk: int = 512,
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     kc = k.reshape(B, n, chunk, Hkv, D).transpose(1, 0, 2, 3, 4)
-    vc = v.reshape(B, n, chunk, Hkv, D).transpose(1, 0, 2, 3, 4)
+    vc = v.reshape(B, n, chunk, Hkv, Dv).transpose(1, 0, 2, 3, 4)
 
     qg = q.reshape(B, L, Hkv, G, D).astype(jnp.float32) * (1.0 / math.sqrt(D))
     row = jnp.arange(L)[:, None] + q_offset
@@ -90,10 +91,10 @@ def flash_ref(q, k, v, *, causal: bool, q_offset=0, chunk: int = 512,
 
     m0 = jnp.full((B, L, Hkv, G), NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, L, Hkv, G), jnp.float32)
-    acc0 = jnp.zeros((B, L, Hkv, G, D), jnp.float32)
+    acc0 = jnp.zeros((B, L, Hkv, G, Dv), jnp.float32)
     (m, l, acc, _), _ = jax.lax.scan(body, (m0, l0, acc0, 0), (kc, vc))
     o = acc / jnp.maximum(l, 1e-30)[..., None]
-    return o.reshape(B, L, H, D).astype(q.dtype)
+    return o.reshape(B, L, H, Dv).astype(q.dtype)
 
 
 def decode_attend(q, k_cache, v_cache, cache_len, *, q_offset=None):
@@ -154,6 +155,8 @@ def merge_partial_attn(o_parts, m_parts, l_parts, axis=0):
 # ---------------------------------------------------------------------------
 
 def attn_init(key, cfg):
+    if cfg.kv_lora_rank:
+        return mla_init(key, cfg)
     d, hd = cfg.d_model, cfg.head_dim
     ks = jax.random.split(key, 4)
     return {
@@ -168,13 +171,19 @@ def attn_apply(p, x, cfg, *, positions, kv_cache=None, cache_index=None,
                cache_layer=None, cross_kv=None, attn_fn=None, use_rope=True):
     """Returns (out, new_kv) where new_kv is (k, v) of this call's tokens.
 
-    kv_cache: optional (k_cache, v_cache) of shape (B, S_max, Hkv, D) --
+    With ``cfg.kv_lora_rank`` the layer is latent attention
+    (:func:`mla_apply`). kv_cache: optional (k_cache, v_cache) of shape
+    (B, S_max, Hkv, D) --
     decode path (x is (B,1,d)); returned with this call's tokens written
     at ``cache_index``. With ``cache_layer`` the caches are every layer's,
     (L, B, S_max, Hkv, D): only layer ``cache_layer``'s new positions are
     written, and that layer is read where it is stored. cross_kv:
     precomputed (k, v) for cross-attention (no rope, no cache write).
     """
+    if cfg.kv_lora_rank:
+        return mla_apply(p, x, cfg, positions=positions, kv_cache=kv_cache,
+                         cache_index=cache_index, cache_layer=cache_layer,
+                         attn_fn=attn_fn)
     B, L, _ = x.shape
     H, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     dt = jnp.dtype(cfg.compute_dtype)
@@ -214,3 +223,142 @@ def attn_apply(p, x, cfg, *, positions, kv_cache=None, cache_index=None,
     # keep collected KV sharded (prefill cache assembly): without this the
     # scan's stacked ys replicate over 'model' when Hkv < TP degree
     return out, (pctx.constrain(k, "kv_collect"), pctx.constrain(v, "kv_collect"))
+
+
+# ---------------------------------------------------------------------------
+# Latent attention (MLA, DeepSeek-V2/V3 without a query LoRA)
+#
+#   q = x W_q                      -> q_nope (H x nope), q_pe (H x rope)
+#   [c_kv | k_pe] = x W_kv_a;  c_kv <- RMSNorm(c_kv)
+#   [k_nope | v] = c_kv W_kv_b     (per head)
+#   RoPE on q_pe and on k_pe, one k_pe shared by every head
+#   o = softmax([q_nope | q_pe] . [k_nope | k_pe] / sqrt(nope + rope)) v
+#   out = o W_o
+#
+# The cache holds what the keys and values are made from: the normalised
+# c_kv and the roped k_pe, kv_lora_rank + rope values a token, in two
+# arrays (a latent is the pair). Decode is absorbed: q_nope W_UK^T attends
+# over c_kv, q_pe over k_pe, and W_UV is applied after the weighted sum, so
+# no key or value is ever expanded.
+# ---------------------------------------------------------------------------
+
+# the latent's RMSNorm keeps its module's default eps in DeepSeek-V3's code
+# (the decoder's norms take rms_norm_eps)
+MLA_NORM_EPS = 1e-6
+
+
+def mla_init(key, cfg):
+    d, H, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": layers.dense_init(ks[0], d, H * (nope + rope)),
+        "wkv_a": layers.dense_init(ks[1], d, r + rope),
+        "kv_norm": layers.norm_init("rmsnorm", r),
+        "wkv_b": layers.dense_init(ks[2], r, H * (nope + vd)),
+        "wo": layers.dense_init(ks[3], H * vd, d),
+    }
+
+
+def _mla_project(p, x, cfg, positions):
+    """x (B, T, d) -> q_nope (B, T, H, nope), roped q_pe (B, T, H, rope)
+    and the tokens' latent: normalised c_kv (B, T, r), roped k_pe (B, T,
+    rope)."""
+    B, T, _ = x.shape
+    H, r = cfg.num_heads, cfg.kv_lora_rank
+    nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    dt = jnp.dtype(cfg.compute_dtype)
+    q = layers.dense(p["wq"], x, dtype=dt).reshape(B, T, H, nope + rope)
+    q_pe = layers.rope(q[..., nope:], positions, cfg.rope_theta,
+                       interleaved=True)
+    kv = layers.dense(p["wkv_a"], x, dtype=dt)
+    c = layers.apply_norm("rmsnorm", p["kv_norm"], kv[..., :r],
+                          eps=MLA_NORM_EPS)
+    k_pe = layers.rope(kv[..., None, r:], positions, cfg.rope_theta,
+                       interleaved=True)[..., 0, :]
+    return q[..., :nope], q_pe, (c, k_pe)
+
+
+def _mla_wkv_b(p, cfg):
+    """W_kv_b as (r, H, nope + v): per head, W_UK then W_UV."""
+    r, H = cfg.kv_lora_rank, cfg.num_heads
+    w = p["wkv_b"]["w"].astype(jnp.dtype(cfg.compute_dtype))
+    return w.reshape(r, H, cfg.qk_nope_head_dim + cfg.v_head_dim)
+
+
+def mla_attend_expanded(p, q_nope, q_pe, latent, cfg, *, attn_fn=None):
+    """Causal attention over ``latent`` (c_kv (B, S, r), k_pe (B, S,
+    rope)) with its keys and values expanded through W_kv_b: the prefill's
+    path, and the plain form the absorbed decode is checked against.
+    -> (B, T, H * v)."""
+    c, k_pe = latent
+    B, S, _ = c.shape
+    T = q_nope.shape[1]
+    H, nope = cfg.num_heads, cfg.qk_nope_head_dim
+    dt = jnp.dtype(cfg.compute_dtype)
+    kv = jnp.einsum("bsc,chn->bshn", c.astype(dt), _mla_wkv_b(p, cfg))
+    k_pe = jnp.broadcast_to(k_pe[:, :, None].astype(dt),
+                            (B, S, H, cfg.qk_rope_head_dim))
+    k = jnp.concatenate([kv[..., :nope], k_pe], axis=-1)
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
+    o = (attn_fn or flash_ref)(q, k, kv[..., nope:], causal=cfg.causal,
+                               q_offset=S - T)
+    return o.reshape(B, T, H * cfg.v_head_dim)
+
+
+def mla_attend_absorbed(p, q_nope, q_pe, latent, cache_len, cfg):
+    """One query token a sequence over the first ``cache_len`` positions of
+    ``latent`` (c_kv (B, S_max, r), k_pe (B, S_max, rope)), absorbed: bf16
+    (compute-dtype) operands, float32 accumulation. -> (B, 1, H * v)."""
+    dt, f32 = jnp.dtype(cfg.compute_dtype), jnp.float32
+    c, k_pe = (a.astype(dt) for a in latent)
+    B, S, _ = c.shape
+    H, nope = cfg.num_heads, cfg.qk_nope_head_dim
+    w = _mla_wkv_b(p, cfg)
+    q_c = jnp.einsum("bhn,chn->bhc", q_nope[:, 0].astype(dt), w[..., :nope],
+                     preferred_element_type=f32).astype(dt)
+    s = jnp.einsum("bhc,bsc->bhs", q_c, c, preferred_element_type=f32) \
+        + jnp.einsum("bhr,bsr->bhs", q_pe[:, 0].astype(dt), k_pe,
+                     preferred_element_type=f32)
+    s = s * (1.0 / math.sqrt(nope + cfg.qk_rope_head_dim))
+    valid = jnp.arange(S)[None, :] < jnp.broadcast_to(
+        jnp.asarray(cache_len), (B,))[:, None]
+    s = jnp.where(valid[:, None, :], s, NEG_INF)
+    pr = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    o_c = jnp.einsum("bhs,bsc->bhc", pr.astype(dt), c,
+                     preferred_element_type=f32)
+    o_c = o_c / jnp.sum(pr, axis=-1)[..., None]
+    o = jnp.einsum("bhc,chv->bhv", o_c.astype(dt), w[..., nope:],
+                   preferred_element_type=f32)
+    return o.reshape(B, 1, H * cfg.v_head_dim).astype(dt)
+
+
+def mla_apply(p, x, cfg, *, positions, kv_cache=None, cache_index=None,
+              cache_layer=None, attn_fn=None):
+    """Latent attention. Returns (out, latent): without a cache, the
+    latent (c_kv (B, T, r), k_pe (B, T, rope)) of this call's tokens; with
+    ``kv_cache`` (the latent cache, (B, S_max, r) and (B, S_max, rope), or
+    every layer's, with a leading L, with ``cache_layer``), one new token a
+    sequence written at ``cache_index`` and attended absorbed, and the
+    cache returned."""
+    T = x.shape[1]
+    dt = jnp.dtype(cfg.compute_dtype)
+    q_nope, q_pe, lat = _mla_project(p, x, cfg, positions)
+    if kv_cache is None:
+        o = mla_attend_expanded(p, q_nope, q_pe, lat, cfg, attn_fn=attn_fn)
+        return layers.dense(p["wo"], o, dtype=dt), lat
+    if cache_layer is None:
+        cache = tuple(jax.lax.dynamic_update_slice_in_dim(
+            a, new.astype(a.dtype), cache_index, axis=1)
+            for a, new in zip(kv_cache, lat))
+        here = cache
+    else:
+        cache = tuple(jax.lax.dynamic_update_slice(
+            a, new[None].astype(a.dtype), (cache_layer, 0, cache_index, 0))
+            for a, new in zip(kv_cache, lat))
+        here = tuple(jax.lax.dynamic_index_in_dim(a, cache_layer,
+                                                  keepdims=False)
+                     for a in cache)
+    with jax.named_scope("mla.attend"):
+        o = mla_attend_absorbed(p, q_nope, q_pe, here, cache_index + T, cfg)
+    return layers.dense(p["wo"], o, dtype=dt), cache

@@ -63,10 +63,17 @@ def apply_norm(kind: str, p, x, eps: float = 1e-5):
 # Rotary position embeddings (llama-style half rotation)
 # ---------------------------------------------------------------------------
 
-def rope(x, positions, theta: float):
-    """x: (..., seq, heads, head_dim), positions: (..., seq)."""
+def rope(x, positions, theta: float, *, interleaved: bool = False):
+    """x: (..., seq, heads, head_dim), positions: (..., seq).
+
+    ``interleaved``: the pairs rotated together are adjacent features
+    (2i, 2i + 1), as DeepSeek-V2/V3 store them; the result has the
+    even features first, then the odd ones (their reference code's
+    order). Otherwise features i and i + head_dim / 2 pair up."""
     hd = x.shape[-1]
     half = hd // 2
+    if interleaved:
+        x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
     freqs = jnp.exp(-math.log(theta) * jnp.arange(0, half, dtype=jnp.float32) / half)
     ang = positions[..., :, None].astype(jnp.float32) * freqs  # (..., seq, half)
     cos = jnp.cos(ang)[..., :, None, :]   # broadcast over heads

@@ -8,6 +8,7 @@ Public API (used by launch/, train/, serve/):
     apply_lm(cfg, params, tokens, ...)      -> (hidden, aux)        train fwd
     prefill_lm(cfg, params, tokens, ...)    -> (hidden, cache)
     decode_lm(cfg, params, cache, tokens)   -> (logits, cache)      1 new token
+        (cfg.reports_routing: and the step's routed counts, see decode_lm)
     init_cache(cfg, batch, max_seq)         -> cache pytree
     unembed(cfg, params, hidden)            -> logits
 """
@@ -34,14 +35,16 @@ REMAT_POLICIES = {
 # Per-layer blocks
 # ---------------------------------------------------------------------------
 
-def _block_init(key, cfg):
+def _block_init(key, cfg, dense: bool = False):
+    """One decoder layer; ``dense``: a dense MLP in an MoE model (its
+    leading layers)."""
     ks = jax.random.split(key, 4)
     p = {
         "attn_norm": layers.norm_init(cfg.norm, cfg.d_model),
         "attn": attention.attn_init(ks[0], cfg),
         "mlp_norm": layers.norm_init(cfg.norm, cfg.d_model),
     }
-    if cfg.family == "moe":
+    if cfg.family == "moe" and not dense:
         p["moe"] = moe.moe_init(ks[1], cfg)
     else:
         p["mlp"] = layers.mlp_init(ks[1], cfg.mlp, cfg.d_model, cfg.d_ff, bias=cfg.bias)
@@ -50,13 +53,15 @@ def _block_init(key, cfg):
 
 def _block_apply(p, x, cfg, *, positions, kv=None, cache_index=None,
                  cache_layer=None, attn_fn=None, moe_impl="einsum"):
+    """-> (x, new_kv, aux): ``aux`` is an MoE layer's load-balance loss,
+    or a dropless one's routed counts (``moe.moe_apply_held``)."""
     h = layers.apply_norm(cfg.norm, p["attn_norm"], x)
     h, new_kv = attention.attn_apply(
         p["attn"], h, cfg, positions=positions, kv_cache=kv,
         cache_index=cache_index, cache_layer=cache_layer, attn_fn=attn_fn)
     x = x + h
     h = layers.apply_norm(cfg.norm, p["mlp_norm"], x)
-    if cfg.family == "moe":
+    if "moe" in p:
         h, aux = moe.moe_apply(p["moe"], h, cfg, impl=moe_impl)
     else:
         h, aux = layers.apply_mlp(cfg.mlp, p["mlp"], h), 0.0
@@ -96,7 +101,12 @@ def init_lm(cfg, key):
         params["unembed"] = layers.dense_init(ks[1], cfg.d_model, cfg.vocab_size)
 
     if cfg.family in ("dense", "moe", "vlm"):
-        params["blocks"] = _stack_init(partial(_block_init, cfg=cfg), ks[2], cfg.num_layers)
+        n_dense = cfg.first_dense_layers
+        params["blocks"] = _stack_init(partial(_block_init, cfg=cfg), ks[2],
+                                       cfg.num_layers - n_dense)
+        if n_dense:
+            params["dense_blocks"] = _stack_init(
+                partial(_block_init, cfg=cfg, dense=True), ks[5], n_dense)
     elif cfg.family == "ssm":
         params["blocks"] = _stack_init(partial(_rwkv_layer_init, cfg=cfg), ks[2], cfg.num_layers)
         params["ln0"] = layers.norm_init("layernorm", cfg.d_model)
@@ -119,6 +129,18 @@ def init_lm(cfg, key):
 
 def init_cache(cfg, batch: int, max_seq: int, dtype=jnp.bfloat16):
     hd, hkv = cfg.head_dim, cfg.num_kv_heads
+    if cfg.kv_lora_rank:    # the latent: c_kv and k_pe per layer
+        cache = {"idx": jnp.zeros((), jnp.int32)}
+        n_dense = cfg.first_dense_layers
+        for stack, n in (("dense_blocks", n_dense),
+                         ("blocks", cfg.num_layers - n_dense)):
+            c, pe = _cache_names(cfg, stack)
+            if n:
+                cache[c] = jnp.zeros((n, batch, max_seq, cfg.kv_lora_rank),
+                                     dtype)
+                cache[pe] = jnp.zeros(
+                    (n, batch, max_seq, cfg.qk_rope_head_dim), dtype)
+        return cache
     if cfg.family in ("dense", "moe", "vlm"):
         return {
             "k": jnp.zeros((cfg.num_layers, batch, max_seq, hkv, hd), dtype),
@@ -155,6 +177,21 @@ def _embed_inputs(cfg, params, tokens, embeds):
     return x
 
 
+def _layer_stacks(params):
+    """The stacked layers in order: an MoE model's leading dense layers
+    first."""
+    return [params[k] for k in ("dense_blocks", "blocks") if k in params]
+
+
+def _cache_names(cfg, stack: str):
+    """The cache arrays of the layers of ``stack`` ("blocks", or an MoE
+    model's leading "dense_blocks", which keep arrays of their own, so that
+    no array is both inside the decode step's layer loop and outside it)."""
+    names = ("c_kv", "k_pe") if cfg.kv_lora_rank else ("k", "v")
+    return tuple(("dense_" + n if stack == "dense_blocks" else n)
+                 for n in names)
+
+
 def _scan_blocks(cfg, body, x, xs, remat: str):
     if remat != "none":
         body = jax.checkpoint(body, policy=REMAT_POLICIES[remat],
@@ -175,8 +212,15 @@ def apply_lm(cfg, params, tokens, *, embeds=None, attn_fn=None,
             h, aux = carry
             h, kv, a = _block_apply(blk, h, cfg, positions=positions,
                                     attn_fn=attn_fn, moe_impl=moe_impl)
+            if cfg.moe_dispatch == "dropless":
+                a = 0.0                 # routed counts; no balance loss
             return (pctx.constrain(h), aux + a), (kv if collect_kv else None)
-        (x, aux), kvs = _scan_blocks(cfg, body, (x, 0.0), params["blocks"], remat)
+        carry, kvs = (x, 0.0), []
+        for stack in _layer_stacks(params):
+            carry, kv = _scan_blocks(cfg, body, carry, stack, remat)
+            kvs.append(kv)
+        (x, aux) = carry
+        kvs = kvs[0] if len(kvs) == 1 else kvs  # one stack: (k, v) as ever
     elif cfg.family == "ssm":
         x = layers.apply_norm("layernorm", params["ln0"], x)
         st0 = rwkv.rwkv_state_init(cfg, B)
@@ -229,11 +273,13 @@ def prefill_lm(cfg, params, tokens, *, embeds=None, attn_fn=None,
     max_seq = max_seq or S
     cache = init_cache(cfg, B, max_seq, dtype=jnp.dtype(cfg.compute_dtype))
     if cfg.family in ("dense", "moe", "vlm"):
-        k, v = kvs  # (L,B,S,hkv,hd)
-        cache["k"] = jax.lax.dynamic_update_slice_in_dim(
-            cache["k"], k.astype(cache["k"].dtype), 0, axis=2)
-        cache["v"] = jax.lax.dynamic_update_slice_in_dim(
-            cache["v"], v.astype(cache["v"].dtype), 0, axis=2)
+        # (L,B,S,hkv,hd) K and V, or latent attention's c_kv and k_pe, of
+        # each stack of layers
+        stacks = [k for k in ("dense_blocks", "blocks") if k in params]
+        for stack, kv in zip(stacks, kvs if len(stacks) > 1 else [kvs]):
+            for name, new in zip(_cache_names(cfg, stack), kv):
+                cache[name] = jax.lax.dynamic_update_slice_in_dim(
+                    cache[name], new.astype(cache[name].dtype), 0, axis=2)
     elif cfg.family == "ssm":
         cache["layers"] = kvs
     elif cfg.family == "hybrid":
@@ -250,24 +296,43 @@ def prefill_lm(cfg, params, tokens, *, embeds=None, attn_fn=None,
 
 
 def decode_lm(cfg, params, cache, tokens):
-    """One decode step. tokens: (B, 1). Returns (logits, new_cache)."""
+    """One decode step. tokens: (B, 1). Returns (logits, new_cache), and
+    where ``cfg.reports_routing`` also the step's routed counts: (MoE layers, held experts) int32, the (token,
+    choice) pairs each held expert was given in each layer."""
     x = _embed_inputs(cfg, params, tokens, None)
     idx = cache["idx"]
     positions = idx + jnp.zeros((1, 1), jnp.int32)
+    counts = None
 
     if cfg.family in ("dense", "moe", "vlm"):
         # the stacked cache rides in the carry, so each layer writes its one
         # new position in place; scanned as xs/ys, every layer's K/V would
         # be sliced out and written back whole
         def body(carry, blk):
-            h, k, v, layer = carry
-            h, (k, v), _ = _block_apply(blk, h, cfg, positions=positions,
-                                        kv=(k, v), cache_index=idx,
-                                        cache_layer=layer)
-            return (pctx.constrain(h, "residual_dec"), k, v, layer + 1), None
-        (x, ks, vs, _), _ = jax.lax.scan(
-            body, (x, cache["k"], cache["v"], jnp.int32(0)), params["blocks"])
-        new_cache = dict(cache, k=ks, v=vs, idx=idx + 1)
+            h, kv, layer = carry
+            h, kv, a = _block_apply(blk, h, cfg, positions=positions,
+                                    kv=kv, cache_index=idx,
+                                    cache_layer=layer)
+            return (pctx.constrain(h, "residual_dec"), kv, layer + 1), \
+                (a if cfg.reports_routing else None)
+        # an MoE model's few leading dense layers are unrolled, on arrays
+        # of their own: in a loop of their own, or on arrays of the main
+        # loop, the cache would be laid out twice and copied whole between
+        written = {}
+        if "dense_blocks" in params:
+            names = _cache_names(cfg, "dense_blocks")
+            carry = (x, tuple(cache[n] for n in names), jnp.int32(0))
+            for i in range(cfg.first_dense_layers):
+                carry, _ = body(carry, jax.tree.map(lambda a: a[i],
+                                                    params["dense_blocks"]))
+            x, kv, _ = carry
+            written.update(zip(names, kv))
+        names = _cache_names(cfg, "blocks")
+        (x, kv, _), counts = jax.lax.scan(
+            body, (x, tuple(cache[n] for n in names), jnp.int32(0)),
+            params["blocks"])
+        written.update(zip(names, kv))
+        new_cache = dict(cache, **written, idx=idx + 1)
     elif cfg.family == "ssm":
         x = layers.apply_norm("layernorm", params["ln0"], x)
 
@@ -301,4 +366,6 @@ def decode_lm(cfg, params, cache, tokens):
         raise ValueError(cfg.family)
 
     x = layers.apply_norm(cfg.norm, params["final_norm"], x)
+    if cfg.reports_routing:
+        return unembed(cfg, params, x), new_cache, counts
     return unembed(cfg, params, x), new_cache

@@ -25,7 +25,8 @@ def fsdp_axes(mesh: Mesh):
     return ("pod", "data") if "pod" in mesh.axis_names else ("data",)
 
 
-_STACKED = re.compile(r"(^|/)(blocks|groups|prologue|enc_blocks|dec_blocks)(/|$)")
+_STACKED = re.compile(
+    r"(^|/)(blocks|dense_blocks|groups|prologue|enc_blocks|dec_blocks)(/|$)")
 _EXTRA_STACK = re.compile(r"(^|/)groups(/|$)")   # zamba groups: (G, M, ...)
 
 
@@ -37,15 +38,26 @@ def _rule(path: str, ndim: int, cfg, moe_sharding: str) -> Tuple:
     def last(name):
         return path.endswith(name) or path.endswith(name + "/w")
 
-    # --- MoE expert tensors (E, d, ff) / (E, ff, d)
+    # --- MoE expert tensors (E, d, ff) / (E, ff, d); a dropless layer's
+    # shared MLP is a dense MLP
+    if "/moe/shared/" in path:
+        return (t, f) if last("wd") else (f, t)
     if "/moe/" in path:
         if last("router"):
             return (f, None)
+        if last("router_bias"):
+            return (None,)
         ep = moe_sharding == "ep"
         if last("wg") or last("wu"):
             return (t, f, None) if ep else (None, f, t)
         if last("wd"):
             return (t, None, f) if ep else (None, t, f)
+
+    # --- latent attention: W_kv_a to the latent (replicated), W_kv_b from it
+    if re.search(r"/attn/wkv_a/w$", path):
+        return (f, None)
+    if re.search(r"/attn/wkv_b/w$", path):
+        return (None, t)
 
     # --- attention projections
     if re.search(r"/(attn|xattn)/w[qkv]/w$", path):
@@ -217,6 +229,11 @@ def cache_specs(cfg, mesh: Mesh, *, batch: int, seq_shard: bool = False):
     else:
         kv = P(None, batch_ax, "model", None, None)     # seq over model
     scalar = P()
+    if cfg.kv_lora_rank:      # one latent for every head: no head axis
+        lat = P(None, batch_ax, seq_ax, None)
+        names = ("c_kv", "k_pe") + (("dense_c_kv", "dense_k_pe")
+                                    if cfg.first_dense_layers else ())
+        return dict({n: lat for n in names}, idx=scalar)
     if cfg.family in ("dense", "moe", "vlm"):
         return {"k": kv, "v": kv, "idx": scalar}
     if cfg.family == "ssm":

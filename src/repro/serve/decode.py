@@ -39,11 +39,13 @@ def make_prefill_step(cfg, *, max_seq: int, remat: str = "full",
 
 
 def make_serve_step(cfg):
+    """The decode step; where ``cfg.reports_routing`` it also returns the
+    step's routed counts (MoE layers, held experts)."""
     def serve_step(params, cache, tokens):
-        """tokens: (B, 1) -> (next_token (B,1), new_cache)."""
-        logits, cache = api.decode(cfg, params, cache, tokens)
+        """tokens: (B, 1) -> (next_token (B,1), new_cache[, routed])."""
+        logits, cache, *routed = api.decode(cfg, params, cache, tokens)
         next_tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-        return next_tok, cache
+        return (next_tok, cache, *routed)
     return serve_step
 
 
@@ -73,8 +75,9 @@ def cache_axes(cfg, params, *, batch: int, max_seq: int, sharding):
         cfg, batch, max_seq, dtype=jnp.dtype(cfg.compute_dtype)))
     auto = Format(Layout.AUTO, sharding)
     formats = jax.tree.map(lambda c: auto if c.ndim else sharding, cache)
+    outs = (sharding, formats) + (sharding,) * cfg.reports_routing
     chosen = jax.jit(make_serve_step(cfg), in_shardings=(None, formats, None),
-                     out_shardings=(sharding, formats)).lower(
+                     out_shardings=outs).lower(
         params, cache, jax.ShapeDtypeStruct((batch, 1), jnp.int32)
     ).compile().input_formats[0][1]
     return jax.tree.map(lambda f: tuple(f.layout.major_to_minor), chosen)
@@ -93,8 +96,8 @@ def compile_serve_step(cfg, params, axes, *, batch: int, max_seq: int,
     step = make_serve_step(cfg)
 
     def serve_step(params, stored, tokens):
-        tok, cache = step(params, load(stored, axes), tokens)
-        return tok, store(cache, axes)
+        tok, cache, *routed = step(params, load(stored, axes), tokens)
+        return (tok, store(cache, axes), *routed)
     stored = jax.tree.map(
         lambda c: jax.ShapeDtypeStruct(c.shape, c.dtype, sharding=sharding),
         jax.eval_shape(lambda: store(api.init_cache(

@@ -22,6 +22,13 @@ only while a profile is being taken (``jax.profiler.start_trace``):
 - ``serve.decode``: the call of the jitted decode step (dispatch);
   ``serve.decode_wait``: the wait for it; ``serve.fetch``: the new tokens
   copied to the host.
+
+Where the model's MoE layers hold a share of the experts
+(``cfg.reports_routing``), each decode step also returns its routed
+counts, fetched with the tokens: ``ServeEngine.routed`` gets one
+(MoE layers, held experts) array a step, the (token, choice) pairs each
+held expert was given, since the latest ``run()`` began (a caller that
+drives ``step()`` itself clears it).
 """
 from __future__ import annotations
 
@@ -101,7 +108,9 @@ def _make_splice(slots: int, axes):
                         and big.shape[ax + 1:] == small.shape[ax + 1:]):
                     return jax.lax.dynamic_update_slice_in_dim(
                         big, small.astype(big.dtype), slot, axis=ax)
-            return big
+            # left as it was, the slot would keep its last request's state
+            raise ValueError(f"no batch axis of {slots} in a cache array of "
+                             f"shape {big.shape} for one of {small.shape}")
         return jax.tree.map(put, cache, store(one, axes))
     return splice
 
@@ -135,6 +144,7 @@ class ServeEngine:
         self.decode_temp_bytes = getattr(mem, "temp_size_in_bytes", None)
         self.steps = 0
         self.finished: List[Request] = []
+        self.routed: List[np.ndarray] = []
         self.prompt_len: Optional[int] = None
 
     def submit(self, req: Request):
@@ -186,8 +196,8 @@ class ServeEngine:
             if all(r is None for r in self.active):
                 return False
             with TraceAnnotation("serve.decode"):
-                next_tok, self.cache = self._decode(self.params, self.cache,
-                                                    self.tokens)
+                next_tok, self.cache, *routed = self._decode(
+                    self.params, self.cache, self.tokens)
             t0 = time.perf_counter()
             with TraceAnnotation("serve.decode_wait"):
                 next_tok.block_until_ready()
@@ -195,7 +205,12 @@ class ServeEngine:
             self.tokens = next_tok
             self.steps += 1
             with TraceAnnotation("serve.fetch"):
-                toks = np.asarray(next_tok)[:, 0]
+                if routed:
+                    toks, counts = jax.device_get((next_tok, routed[0]))
+                    self.routed.append(counts)
+                    toks = toks[:, 0]
+                else:
+                    toks = np.asarray(next_tok)[:, 0]
             for i, req in enumerate(self.active):
                 if req is not None:
                     self._emit(i, req, int(toks[i]))
@@ -204,6 +219,7 @@ class ServeEngine:
     def run(self, max_steps: int = 10_000) -> List[Request]:
         """Serve until the queue drains; returns the requests finished since
         the last ``run()``, in the order they finished."""
+        self.routed.clear()
         while (self.queue or any(self.active)) and self.steps < max_steps:
             self.step()
         done, self.finished = self.finished, []

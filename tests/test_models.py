@@ -59,6 +59,11 @@ def test_decode_matches_full_forward(arch):
         # ample capacity: token dropping differs between batched prefill and
         # one-token decode by design; equivalence holds when nothing drops
         cfg = dataclasses.replace(cfg, capacity_factor=8.0)
+    if cfg.kv_lora_rank:
+        # latent attention's decode is absorbed (its products in another
+        # order than the forward's expanded keys), and a top-k router turns
+        # bf16 rounding into swapped experts: compared in float32
+        cfg = dataclasses.replace(cfg, compute_dtype="float32")
     params = api.init(cfg, KEY)
     B, S = 2, 13
     toks = jax.random.randint(KEY, (B, S + 1), 0, cfg.vocab_size)
@@ -68,7 +73,8 @@ def test_decode_matches_full_forward(arch):
     logits_full = api.unembed(cfg, params, hidden[:, -1:])
     pre = dict(batch, tokens=toks[:, :S])
     _, cache = api.prefill(cfg, params, pre, max_seq=S + cfg.frontend_len + 8)
-    logits_dec, cache2 = api.decode(cfg, params, cache, toks[:, S:S + 1])
+    logits_dec, cache2, *_ = api.decode(cfg, params, cache,
+                                        toks[:, S:S + 1])
     np.testing.assert_allclose(np.asarray(logits_dec, np.float32),
                                np.asarray(logits_full, np.float32),
                                atol=2e-2, rtol=2e-2)

@@ -174,3 +174,38 @@ def test_decode_step_updates_the_cache_in_place(one_chip, arch, slots,
     for f in jax.tree.leaves(compiled.input_formats[0][1]):
         assert f.layout.major_to_minor == tuple(range(len(
             f.layout.major_to_minor)))
+
+
+def test_latent_decode_step_updates_the_cache_in_place(one_chip):
+    """moonlight-16b-a3b.long-decode-32's engine step (32 slots, 2048
+    positions, latent attention over 8 held of 64 routed experts, at full
+    width): the latent cache (c_kv and k_pe, the leading dense layer's in
+    arrays of their own) is donated and written one position per layer, so
+    the step aliases at least the whole cache, needs less scratch than one
+    layer's latent, and never copies a layer or an array whole; it also
+    returns its routed counts."""
+    import dataclasses
+    cfg = dataclasses.replace(registry.get("moonlight-16b-a3b"),
+                              experts_held=8)
+    slots, max_seq = 32, 2048
+    params = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: init_params(cfg, 0)))
+    shape = dict(batch=slots, max_seq=max_seq, sharding=one_chip)
+    axes = cache_axes(cfg, params, **shape)
+    compiled = compile_serve_step(cfg, params, axes, **shape)
+    width = cfg.kv_lora_rank + cfg.qk_rope_head_dim
+    dims = set()
+    for name, w in (("c_kv", cfg.kv_lora_rank), ("k_pe", cfg.qk_rope_head_dim)):
+        for n, arr in ((cfg.num_layers - 1, name), (1, "dense_" + name)):
+            stack = (n, slots, max_seq, w)
+            stored = tuple(stack[i] for i in axes[arr])
+            dims |= {d for s in (stack, stored)
+                     for d in (s, s[1:], (1,) + s[1:])}
+    layer_bytes = 2 * slots * max_seq * width
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cfg.num_layers * layer_bytes
+    assert mem.temp_size_in_bytes < layer_bytes
+    assert _hbm_copies(compiled.as_text(), dims) == []
+    routed = compiled.out_info[2]
+    assert routed.shape == (cfg.num_layers - 1, 8)
