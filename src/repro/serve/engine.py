@@ -4,11 +4,15 @@ predictor (the paper's model as a production feature — DESIGN.md §4).
 
 Slots hold independent requests; finished slots are refilled from the queue
 without stopping the decode loop. Designed so the decode step is the same
-function the dry-run lowers for the decode_32k/long_500k cells. The engine
-compiles it once, with the cache donated and its axes stored in the order
-of the layout the compiler chose (``serve.decode.compile_serve_step``);
-the cache is made, and each admission spliced into it, in that order and
-in place.
+function the dry-run lowers for the decode_32k/long_500k cells. The prefill
+and unembed of one request are one program too (``make_prefill_step``),
+compiled at the engine's first admission for its fixed prompt length and
+only dispatched after that; ``ServeEngine.prefill_compiles`` counts the
+prefill programs the engine has built (1 after any number of admissions).
+The engine compiles the decode step once, with the cache donated and its
+axes stored in the order of the layout the compiler chose
+(``serve.decode.compile_serve_step``); the cache is made, and each
+admission spliced into it, in that order and in place.
 
 Each phase of ``step()`` is a host span in the profiler's trace, recorded
 only while a profile is being taken (``jax.profiler.start_trace``):
@@ -16,9 +20,10 @@ only while a profile is being taken (``jax.profiler.start_trace``):
 - ``serve.step``: the whole of ``step()``; its time outside its children
   is the slot loop and other bookkeeping.
 - ``serve.admit`` (stats ``rid``, ``slot``): one request's admission, made
-  of ``serve.prefill`` (prefill and unembed, tracing and lowering
-  included), ``serve.first_token`` (the wait for its first token) and
-  ``serve.splice`` (its cache and token into the slot).
+  of ``serve.prefill`` (the dispatch of the compiled prefill; at the
+  engine's first admission, its compile too), ``serve.first_token`` (the
+  wait for its first token) and ``serve.splice`` (its cache and token into
+  the slot).
 - ``serve.decode``: the call of the jitted decode step (dispatch);
   ``serve.decode_wait``: the wait for it; ``serve.fetch``: the new tokens
   copied to the host.
@@ -42,7 +47,8 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.models import api
-from repro.serve.decode import cache_axes, compile_serve_step, store
+from repro.serve.decode import (cache_axes, compile_serve_step,
+                                make_prefill_step, store)
 
 
 @dataclass
@@ -125,7 +131,11 @@ class ServeEngine:
         self.straggler = straggler or StragglerPolicy()
         self.queue: List[Request] = []
         self.active: List[Optional[Request]] = [None] * slots
-        self.tokens = jnp.zeros((slots, 1), jnp.int32)
+        tokens = jnp.zeros((slots, 1), jnp.int32)
+        # committed to its device, as the decode step's outputs that take
+        # the place of it and of the cache are: the splice and the token
+        # write then compile once, not again when those come back
+        self.tokens = jax.device_put(tokens, tokens.sharding)
         shape = dict(batch=slots, max_seq=max_seq,
                      sharding=self.tokens.sharding)
         self.cache_axes = cache_axes(cfg, params, **shape)
@@ -133,7 +143,7 @@ class ServeEngine:
                                           **shape)
         self.cache = jax.jit(lambda: store(api.init_cache(
             cfg, slots, max_seq, dtype=jnp.dtype(cfg.compute_dtype)),
-            self.cache_axes))()
+            self.cache_axes), out_shardings=self.tokens.sharding)()
         self._splice = jax.jit(_make_splice(slots, self.cache_axes),
                                donate_argnums=(0,))
         mem = self._decode.memory_analysis()
@@ -146,6 +156,8 @@ class ServeEngine:
         self.finished: List[Request] = []
         self.routed: List[np.ndarray] = []
         self.prompt_len: Optional[int] = None
+        self._prefill = None            # compiled at the first admission
+        self.prefill_compiles = 0
 
     def submit(self, req: Request):
         # fixed prompt length per engine instance (scalar cache index);
@@ -159,18 +171,22 @@ class ServeEngine:
 
     # --------------------------------------------------------------
     def _prefill_slot(self, slot: int, req: Request):
-        """Single-request prefill into the shared cache (slot-batched). The
-        prompt's last position gives the request's first token."""
+        """Single-request prefill into the shared cache (slot-batched), by
+        the engine's compiled prefill; the prompt's last position gives the
+        request's first token."""
         req.t_admit = time.perf_counter()
         with TraceAnnotation("serve.admit", rid=req.rid, slot=slot):
             with TraceAnnotation("serve.prefill"):
-                toks = jnp.asarray(req.prompt, jnp.int32)[None]
-                hidden, cache1 = api.prefill(self.cfg, self.params,
-                                             {"tokens": toks},
-                                             max_seq=self.max_seq)
-                logits = api.unembed(self.cfg, self.params, hidden[:, -1])
+                batch = {"tokens": np.asarray(req.prompt, np.int32)[None]}
+                if self._prefill is None:
+                    # params an argument: captured, they would be constants
+                    self._prefill = jax.jit(make_prefill_step(
+                        self.cfg, max_seq=self.max_seq)).lower(
+                            self.params, batch).compile()
+                    self.prefill_compiles += 1
+                next_tok, cache1 = self._prefill(self.params, batch)
             with TraceAnnotation("serve.first_token"):
-                first = int(jnp.argmax(logits[0]))
+                first = int(np.asarray(next_tok)[0, 0])
             with TraceAnnotation("serve.splice"):
                 self.cache = self._splice(self.cache, cache1, slot)
                 self.tokens = self.tokens.at[slot, 0].set(first)

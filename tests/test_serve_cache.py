@@ -2,7 +2,10 @@
 layer into the cache it was given, and its tokens are a cache-free
 forward's greedy tokens, wave after wave of requests through the same
 slots; with the cache's axes stored in the order the compiler chose here
-(the logical one on the CPU) and in the order a v5e chooses."""
+(the logical one on the CPU) and in the order a v5e chooses. Its prefill
+is one program, built at the first admission, that later admissions only
+dispatch, and gives the eager prefill's first token and cache (dense and
+latent caches)."""
 from __future__ import annotations
 
 import dataclasses
@@ -11,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import monitoring
 
 from repro.configs import registry
 from repro.models import api, transformer
@@ -114,3 +118,60 @@ def test_two_waves_match_a_cache_free_forward(axes):
     np.testing.assert_array_equal(np.argmax(np.asarray(logits), -1),
                                   seqs[:, PROMPT:])
 
+
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+PREFILL_ARCHS = pytest.mark.parametrize(
+    "arch", ["qwen2.5-3b", "moonlight-16b-a3b"], ids=["gqa", "mla-moe"])
+
+
+@PREFILL_ARCHS
+def test_later_admissions_build_no_program(arch):
+    """Once the first request is served, five more of its length, in three
+    waves through both slots, compile nothing: not the prefill, the
+    splice, the token write nor the decode step."""
+    cfg, params = _model(arch)
+    eng = ServeEngine(cfg, params, slots=2, max_seq=MAX_SEQ)
+    first, *later = _requests(cfg, 6, 3)
+    eng.submit(first)
+    eng.run()
+    assert eng.prefill_compiles == 1
+    compiles = []
+
+    def count(event, secs, **_):
+        if event == COMPILE_EVENT:
+            compiles.append(event)
+    monitoring.register_event_duration_secs_listener(count)
+    try:
+        for r in later:
+            eng.submit(r)
+        assert len(eng.run()) == 5
+    finally:
+        monitoring.unregister_event_duration_listener(count)
+    assert compiles == [] and eng.prefill_compiles == 1
+
+
+@PREFILL_ARCHS
+def test_compiled_prefill_equals_the_eager_one(arch):
+    """Two requests admitted into both slots: each first token is the
+    eager prefill's and unembed's greedy token, and each slot holds the
+    eager prefill's cache at the prompt's positions."""
+    cfg, params = _model(arch)
+    eng = ServeEngine(cfg, params, slots=2, max_seq=MAX_SEQ)
+    reqs = _requests(cfg, 2, 4)
+    for r in reqs:
+        eng.submit(r)
+    eng.step()                  # admits both, then writes position PROMPT
+    cache = _logical(eng, eng.cache)
+    for slot, r in enumerate(reqs):
+        toks = jnp.asarray(r.prompt, jnp.int32)[None]
+        hidden, want = api.prefill(cfg, params, {"tokens": toks},
+                                   max_seq=MAX_SEQ)
+        logits = api.unembed(cfg, params, hidden[:, -1])
+        assert r.out[0] == int(jnp.argmax(logits[0]))
+        for name, got in cache.items():
+            if name != "idx":
+                np.testing.assert_allclose(
+                    got[:, slot, :PROMPT],
+                    np.asarray(want[name])[:, 0, :PROMPT],
+                    rtol=1e-5, atol=1e-5, err_msg=name)
