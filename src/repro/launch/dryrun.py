@@ -1,16 +1,16 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production meshes, record memory/cost/collective analysis.
 
     PYTHONPATH=src python -m repro.launch.dryrun [--arch A] [--shape S]
         [--mesh single|multi|both] [--out results/dryrun.json]
 
-Results are written incrementally (resumable; --force recomputes)."""
+``main()`` sets ``XLA_FLAGS`` to 512 host devices before its first device
+query, so it runs in a process of its own. Results are written
+incrementally (resumable; --force recomputes)."""
 
 import argparse
 import json
+import os
 import time
 import traceback
 from pathlib import Path
@@ -25,9 +25,9 @@ from repro.utils.hlo import collective_bytes, hlo_cost, xla_cost_analysis
 OUT_DEFAULT = "results/dryrun.json"
 
 
-def run_cell(cfg, shape, mesh, mesh_kind: str, plan=None) -> dict:
+def run_cell(cfg, shape, mesh, mesh_kind: str) -> dict:
     t0 = time.time()
-    cell = specs_lib.build_cell(cfg, shape, mesh, plan=plan)
+    cell = specs_lib.build_cell(cfg, shape, mesh)
     with mesh:
         jitted = jax.jit(
             cell["fn"],
@@ -55,7 +55,7 @@ def run_cell(cfg, shape, mesh, mesh_kind: str, plan=None) -> dict:
         "kind": cell["meta"]["kind"],
         "tokens": cell["meta"]["tokens"],
         "plan": {k: getattr(plan, k) for k in
-                 ("microbatches", "remat", "moe_impl", "moe_sharding",
+                 ("microbatches", "remat", "moe_sharding",
                   "opt_dtype", "grad_dtype", "seq_shard_acts",
                   "seq_shard_cache")},
         "flops_per_device": hc["flops"],
@@ -79,6 +79,7 @@ def run_cell(cfg, shape, mesh, mesh_kind: str, plan=None) -> dict:
 
 
 def main():
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

@@ -1,5 +1,7 @@
-"""Production serving launcher: continuous-batching engine over the same
-decode step the dry-run lowers, with SimFA-predicted straggler deadlines.
+"""Serving launcher: the continuous-batching engine over the same decode
+step the dry-run lowers, with a fixed straggler deadline (0.5 s x 10). It
+prints SimFA-TPU and SimFA-H800 decode predictions for the run's shape;
+nothing on the serving path reads them.
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-3b --reduced \
         --requests 8 --slots 4

@@ -2,16 +2,13 @@
 for every (arch x shape x mesh) dry-run cell.
 
 ``plan_cell`` applies the memory napkin math (16 GiB/chip budget) to choose
-microbatch count, optimizer-state dtype, and activation sequence-sharding;
-§Perf overrides land in PERF_OVERRIDES so the hillclimbed plans are explicit
-and reproducible.
+microbatch count, optimizer-state dtype, and activation sequence-sharding
+from the configuration, the shape and the mesh.
 """
 from __future__ import annotations
 
 import dataclasses
-import os
 from dataclasses import dataclass
-from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -32,45 +29,12 @@ HBM_BUDGET = 14.5e9          # leave headroom under 16 GiB/chip
 class CellPlan:
     microbatches: int = 1
     remat: str = "full"
-    moe_impl: str = "einsum"
     moe_sharding: str = "auto"
     opt_dtype: str = "float32"
     grad_dtype: str = "float32"
     seq_shard_acts: bool = False
     seq_shard_cache: bool = False      # SP for long-context decode
     loss_chunk: int = 2048
-    # §Perf knobs (enabled per-cell through PERF_OVERRIDES; the defaults
-    # are the paper-faithful baseline)
-    cast_params: str = "none"          # bf16 fwd/bwd params -> bf16 FSDP AG
-    grad_acc_sharded: bool = False     # reduce-scatter grads onto FSDP shards
-    attn_chunk: int = 512              # flash KV chunk size
-    attn_pv_bf16: bool = False         # FA3-style P tile FP32->bf16 for P@V
-    moe_token_local: bool = False      # pin MoE dispatch buffers to token
-                                       # sharding (stops expert replication)
-    notes: str = ""
-
-
-# §Perf hillclimb results land here: (arch, shape) -> overrides. Applied
-# only when REPRO_PERF=1 so the default dry-run measures the paper-faithful
-# baseline; `REPRO_PERF=1 python -m repro.launch.dryrun --out
-# results/dryrun_perf.json` measures the optimized plans (EXPERIMENTS.md
-# §Perf records both).
-PERF_OVERRIDES: Dict[Tuple[str, str, str], Dict[str, Any]] = {
-    # A4: mb=16 triggered pathological per-mb collectives (B_local=1 ->
-    # partitioner replication); mb=8 cuts collective 502->74s, peak 38->17.5.
-    # The multi-pod mesh has 32-way FSDP (local batch 8), so its microbatch
-    # count halves again to keep B_local_mb >= 2.
-    ("grok-1-314b", "train_4k", "16x16"): {"microbatches": 8},
-    ("grok-1-314b", "train_4k", "2x16x16"): {"microbatches": 4},
-    # B1: halve the FSDP re-gather & weight-grad reduce passes
-    ("command-r-plus-104b", "train_4k", "16x16"): {"microbatches": 2},
-    ("command-r-plus-104b", "train_4k", "2x16x16"): {"microbatches": 2},
-    # C1+C2+C4: sequence-parallel prefill + 4x flash chunk + FA3 P-tile cast
-    ("command-r-plus-104b", "prefill_32k", "16x16"): {
-        "seq_shard_acts": True, "attn_chunk": 2048, "attn_pv_bf16": True},
-    ("command-r-plus-104b", "prefill_32k", "2x16x16"): {
-        "seq_shard_acts": True, "attn_chunk": 2048, "attn_pv_bf16": True},
-}
 
 
 def plan_cell(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh) -> CellPlan:
@@ -115,10 +79,6 @@ def plan_cell(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh) -> CellPlan:
     elif shape.kind == "decode" and shape.global_batch < fsdp:
         # batch can't fill the data axis -> shard the KV sequence instead
         plan = dataclasses.replace(plan, seq_shard_cache=True)
-    if os.environ.get("REPRO_PERF") == "1":
-        key = (cfg.name, shape.name, "x".join(map(str, mesh.devices.shape)))
-        if key in PERF_OVERRIDES:
-            plan = dataclasses.replace(plan, **PERF_OVERRIDES[key])
     return plan
 
 
@@ -162,11 +122,6 @@ def _with_activation_ctx(fn, plan: CellPlan, mesh: Mesh, cfg=None):
     residual = P(dpa, "model" if plan.seq_shard_acts else None, None)
     residual_dec = P(None if plan.seq_shard_cache else dpa, None, None)
     kinds = dict(residual=residual, residual_dec=residual_dec)
-    if plan.moe_token_local:
-        # dispatched expert buffers (n_groups, E, cap, d): groups carry the
-        # flattened token dim -> same axes the residual tokens shard over
-        tok = (tuple(dp) + ("model",)) if plan.seq_shard_acts else dpa
-        kinds["moe_tokens"] = P(tok, None, None, None)
     if cfg is not None and cfg.num_kv_heads:
         heads_div = cfg.num_kv_heads % mesh.shape["model"] == 0
         if heads_div:
@@ -185,11 +140,10 @@ def _with_activation_ctx(fn, plan: CellPlan, mesh: Mesh, cfg=None):
     return wrapped
 
 
-def build_cell(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh,
-               plan: Optional[CellPlan] = None):
-    """Returns dict(fn, args (ShapeDtypeStructs), in_shardings,
-    out_shardings, donate, meta) ready for jit().lower()."""
-    plan = plan or plan_cell(cfg, shape, mesh)
+def build_cell(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh):
+    """Returns dict(fn, args (ShapeDtypeStructs), in_shardings, donate,
+    plan, meta) ready for jit().lower()."""
+    plan = plan_cell(cfg, shape, mesh)
     key = jax.random.PRNGKey(0)
 
     param_struct = jax.eval_shape(lambda: api.init(cfg, key))
@@ -206,14 +160,10 @@ def build_cell(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh,
     if shape.kind == "train":
         run = trainer.RunConfig(
             microbatches=plan.microbatches, remat=plan.remat,
-            moe_impl=plan.moe_impl, loss_chunk=plan.loss_chunk,
-            grad_dtype=plan.grad_dtype, cast_params=plan.cast_params,
-            attn_chunk=plan.attn_chunk, attn_pv_bf16=plan.attn_pv_bf16,
+            loss_chunk=plan.loss_chunk, grad_dtype=plan.grad_dtype,
             opt=opt.OptConfig())
-        step = _with_activation_ctx(
-            trainer.make_train_step(
-                cfg, run, grad_specs=pspecs if plan.grad_acc_sharded else None),
-            plan, mesh, cfg)
+        step = _with_activation_ctx(trainer.make_train_step(cfg, run),
+                                    plan, mesh, cfg)
         opt_dt = jnp.dtype(plan.opt_dtype)
         m_struct = _cast_float(param_struct, opt_dt)
         state_struct = trainer.TrainState(
@@ -236,10 +186,7 @@ def build_cell(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh,
 
     if shape.kind == "prefill":
         step = _with_activation_ctx(
-            serve_decode.make_prefill_step(
-                cfg, max_seq=shape.seq_len, remat=plan.remat,
-                attn_chunk=plan.attn_chunk, cast_params=plan.cast_params,
-                attn_pv_bf16=plan.attn_pv_bf16),
+            serve_decode.make_prefill_step(cfg, max_seq=shape.seq_len),
             plan, mesh, cfg)
         return dict(
             fn=step, args=(serve_params, bstruct),

@@ -21,7 +21,7 @@ def init(cfg, key):
 
 
 def forward_hidden(cfg, params, batch: Dict[str, Any], *, attn_fn=None,
-                   remat: str = "full", moe_impl: str = "einsum"):
+                   remat: str = "full"):
     """Training forward to final hidden states. Returns (hidden, aux)."""
     if cfg.family == "encdec":
         enc_h = encdec.encode(cfg, params, batch["frames"], attn_fn=attn_fn,
@@ -31,7 +31,7 @@ def forward_hidden(cfg, params, batch: Dict[str, Any], *, attn_fn=None,
         return hidden, 0.0
     hidden, aux, _ = transformer.apply_lm(
         cfg, params, batch["tokens"], embeds=batch.get("embeds"),
-        attn_fn=attn_fn, remat=remat, moe_impl=moe_impl)
+        attn_fn=attn_fn, remat=remat)
     return hidden, aux
 
 

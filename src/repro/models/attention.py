@@ -1,9 +1,10 @@
 """Attention: GQA projections + FlashAttention-style chunked online softmax.
 
 ``flash_ref`` is the pure-jnp online-softmax implementation (algorithmically
-FlashAttention, scanned over KV chunks) used (a) as the oracle for the Pallas
-kernels and (b) as the lowering path in the multi-pod dry-run (Pallas TPU
-kernels do not lower on the CPU host platform; see DESIGN.md §8).
+FlashAttention, scanned over KV chunks): the attention of every family's
+training forward and prefill unless a caller passes ``attn_fn``, and the
+oracle for the Pallas kernels in ``repro.kernels``. Decode attends over the
+cache with ``decode_attend`` (or ``mla_attend_absorbed``).
 """
 from __future__ import annotations
 
@@ -39,16 +40,12 @@ def attention_naive(q, k, v, *, causal: bool, q_offset=0):
     return o.reshape(B, L, H, D).astype(q.dtype)
 
 
-def flash_ref(q, k, v, *, causal: bool, q_offset=0, chunk: int = 512,
-              pv_bf16: bool = False):
+def flash_ref(q, k, v, *, causal: bool, q_offset=0, chunk: int = 512):
     """Online-softmax attention scanned over KV chunks (pure jnp).
 
     Never materializes the (L, S) score matrix for more than one KV chunk;
     this is the FlashAttention dataflow expressed at the XLA level. ``v``
     may have another head size than ``q`` and ``k`` (latent attention).
-    ``pv_bf16`` stores the probability tile at half width for the PV matmul
-    (FA3 §5.2 does exactly this FP32->FP16 conversion before P@V) — §Perf
-    knob that cuts the dominant score-tile HBM traffic.
     """
     B, L, H, D = q.shape
     S, Hkv, Dv = k.shape[1], k.shape[2], v.shape[-1]
@@ -79,14 +76,8 @@ def flash_ref(q, k, v, *, causal: bool, q_offset=0, chunk: int = 512,
         p = jnp.exp(s - m_new[..., None])
         corr = jnp.exp(m - m_new)
         l_new = l * corr + jnp.sum(p, axis=-1)
-        if pv_bf16:
-            acc_new = acc * corr[..., None] + jnp.einsum(
-                "blhgc,bchd->blhgd", p.astype(jnp.bfloat16),
-                vj.astype(jnp.bfloat16),
-                preferred_element_type=jnp.float32)
-        else:
-            acc_new = acc * corr[..., None] + jnp.einsum(
-                "blhgc,bchd->blhgd", p, vj.astype(jnp.float32))
+        acc_new = acc * corr[..., None] + jnp.einsum(
+            "blhgc,bchd->blhgd", p, vj.astype(jnp.float32))
         return (m_new, l_new, acc_new, j + 1), None
 
     m0 = jnp.full((B, L, Hkv, G), NEG_INF, jnp.float32)
@@ -172,13 +163,12 @@ def attn_apply(p, x, cfg, *, positions, kv_cache=None, cache_index=None,
     """Returns (out, new_kv) where new_kv is (k, v) of this call's tokens.
 
     With ``cfg.kv_lora_rank`` the layer is latent attention
-    (:func:`mla_apply`). kv_cache: optional (k_cache, v_cache) of shape
-    (B, S_max, Hkv, D) --
-    decode path (x is (B,1,d)); returned with this call's tokens written
-    at ``cache_index``. With ``cache_layer`` the caches are every layer's,
-    (L, B, S_max, Hkv, D): only layer ``cache_layer``'s new positions are
-    written, and that layer is read where it is stored. cross_kv:
-    precomputed (k, v) for cross-attention (no rope, no cache write).
+    (:func:`mla_apply`). kv_cache: optional (k_cache, v_cache), every
+    layer's cache, (L, B, S_max, Hkv, D), given with ``cache_layer``, the
+    layer this call is (decode path, x is (B,1,d)): this call's tokens are
+    written into that layer at ``cache_index``, the layer is read where it
+    is stored, and both caches are returned. cross_kv: precomputed (k, v)
+    for cross-attention (no rope, no cache write).
     """
     if cfg.kv_lora_rank:
         return mla_apply(p, x, cfg, positions=positions, kv_cache=kv_cache,
@@ -204,16 +194,11 @@ def attn_apply(p, x, cfg, *, positions, kv_cache=None, cache_index=None,
     if kv_cache is not None:
         k_cache, v_cache = kv_cache
         idx = cache_index
-        if cache_layer is None:
-            k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, k.astype(k_cache.dtype), idx, axis=1)
-            v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, v.astype(v_cache.dtype), idx, axis=1)
-            k_att, v_att = k_cache, v_cache
-        else:
-            at = (cache_layer, 0, idx, 0, 0)
-            k_cache = jax.lax.dynamic_update_slice(k_cache, k[None].astype(k_cache.dtype), at)
-            v_cache = jax.lax.dynamic_update_slice(v_cache, v[None].astype(v_cache.dtype), at)
-            k_att = jax.lax.dynamic_index_in_dim(k_cache, cache_layer, keepdims=False)
-            v_att = jax.lax.dynamic_index_in_dim(v_cache, cache_layer, keepdims=False)
+        at = (cache_layer, 0, idx, 0, 0)
+        k_cache = jax.lax.dynamic_update_slice(k_cache, k[None].astype(k_cache.dtype), at)
+        v_cache = jax.lax.dynamic_update_slice(v_cache, v[None].astype(v_cache.dtype), at)
+        k_att = jax.lax.dynamic_index_in_dim(k_cache, cache_layer, keepdims=False)
+        v_att = jax.lax.dynamic_index_in_dim(v_cache, cache_layer, keepdims=False)
         o = decode_attend(q, k_att, v_att, idx + L)
         out = layers.dense(p["wo"], o.reshape(B, L, H * hd), dtype=dt)
         return out, (k_cache, v_cache)
@@ -337,28 +322,21 @@ def mla_apply(p, x, cfg, *, positions, kv_cache=None, cache_index=None,
               cache_layer=None, attn_fn=None):
     """Latent attention. Returns (out, latent): without a cache, the
     latent (c_kv (B, T, r), k_pe (B, T, rope)) of this call's tokens; with
-    ``kv_cache`` (the latent cache, (B, S_max, r) and (B, S_max, rope), or
-    every layer's, with a leading L, with ``cache_layer``), one new token a
-    sequence written at ``cache_index`` and attended absorbed, and the
-    cache returned."""
+    ``kv_cache`` (every layer's latent cache, (L, B, S_max, r) and (L, B,
+    S_max, rope), with ``cache_layer`` the layer this call is), one new
+    token a sequence written into that layer at ``cache_index`` and
+    attended absorbed where it is stored, and the cache returned."""
     T = x.shape[1]
     dt = jnp.dtype(cfg.compute_dtype)
     q_nope, q_pe, lat = _mla_project(p, x, cfg, positions)
     if kv_cache is None:
         o = mla_attend_expanded(p, q_nope, q_pe, lat, cfg, attn_fn=attn_fn)
         return layers.dense(p["wo"], o, dtype=dt), lat
-    if cache_layer is None:
-        cache = tuple(jax.lax.dynamic_update_slice_in_dim(
-            a, new.astype(a.dtype), cache_index, axis=1)
-            for a, new in zip(kv_cache, lat))
-        here = cache
-    else:
-        cache = tuple(jax.lax.dynamic_update_slice(
-            a, new[None].astype(a.dtype), (cache_layer, 0, cache_index, 0))
-            for a, new in zip(kv_cache, lat))
-        here = tuple(jax.lax.dynamic_index_in_dim(a, cache_layer,
-                                                  keepdims=False)
-                     for a in cache)
+    cache = tuple(jax.lax.dynamic_update_slice(
+        a, new[None].astype(a.dtype), (cache_layer, 0, cache_index, 0))
+        for a, new in zip(kv_cache, lat))
+    here = tuple(jax.lax.dynamic_index_in_dim(a, cache_layer, keepdims=False)
+                 for a in cache)
     with jax.named_scope("mla.attend"):
         o = mla_attend_absorbed(p, q_nope, q_pe, here, cache_index + T, cfg)
     return layers.dense(p["wo"], o, dtype=dt), cache

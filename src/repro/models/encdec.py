@@ -66,10 +66,11 @@ def encode(cfg, params, frames, *, attn_fn=None, remat="full"):
 
 
 def _dec_block(blk, h, cfg, enc_kv, *, positions, kv=None, cache_index=None,
-               attn_fn=None):
+               cache_layer=None, attn_fn=None):
     a = layers.apply_norm(cfg.norm, blk["attn_norm"], h)
     a, new_kv = attention.attn_apply(blk["attn"], a, cfg, positions=positions,
                                      kv_cache=kv, cache_index=cache_index,
+                                     cache_layer=cache_layer,
                                      attn_fn=attn_fn, use_rope=False)
     h = h + a
     xa = layers.apply_norm(cfg.norm, blk["xattn_norm"], h)
@@ -165,15 +166,18 @@ def decode_encdec(cfg, params, cache, tokens):
     x = x + jax.lax.dynamic_slice_in_dim(params["pos_dec"], idx, 1).astype(x.dtype)[None, 0]
     positions = idx + jnp.zeros((1, 1), jnp.int32)
 
-    def body(h, inp):
-        blk, k, v, ck, cv = inp
-        h, (k2, v2) = _dec_block(blk, h, cfg, (ck, cv), positions=positions,
-                                 kv=(k, v), cache_index=idx)
-        return pctx.constrain(h, "residual_dec"), (k2, v2)
+    # the self-attention caches ride in the carry, as the decoder-only
+    # decode's do; the cross caches are read only and stay xs
+    def body(carry, inp):
+        h, kv, layer = carry
+        blk, ck, cv = inp
+        h, kv = _dec_block(blk, h, cfg, (ck, cv), positions=positions,
+                           kv=kv, cache_index=idx, cache_layer=layer)
+        return (pctx.constrain(h, "residual_dec"), kv, layer + 1), None
 
-    x, (ks, vs) = jax.lax.scan(
-        body, x, (params["dec_blocks"], cache["k"], cache["v"],
-                  cache["cross_k"], cache["cross_v"]))
+    (x, (ks, vs), _), _ = jax.lax.scan(
+        body, (x, (cache["k"], cache["v"]), jnp.int32(0)),
+        (params["dec_blocks"], cache["cross_k"], cache["cross_v"]))
     new_cache = dict(cache, k=ks, v=vs, idx=idx + 1)
     x = layers.apply_norm(cfg.norm, params["dec_norm"], x)
     dt = jnp.dtype(cfg.compute_dtype)

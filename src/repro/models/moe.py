@@ -1,17 +1,15 @@
-"""Mixture-of-Experts block: top-k routing with capacity-bounded dispatch,
-or a dropless layer that holds a share of the experts.
+"""Mixture-of-Experts block, one of two layers by ``cfg.moe_dispatch``:
 
-With ``cfg.moe_dispatch == "dropless"`` the layer is one chip's share of an
-expert-parallel deployment (``moe_apply_held``): a float32 router over all
-``num_experts``, the part of the result that the experts it holds give, with
-no token dropped, and the shared experts. Otherwise two interchangeable
-capacity-bounded implementations (bit-compatible where no tokens drop):
-
-* ``einsum`` — GShard-style one-hot dispatch/combine einsums over token
-  groups. Pure pjit, shards under any mesh; dispatch FLOPs overhead is
-  ~(2/3)·T_group·cf/d_ff (≈4% for dbrx, ≈1% for grok). Default.
-* ``scatter`` — sort + scatter-add dispatch (no one-hot FLOPs); candidate
-  for §Perf hillclimbing (bandwidth-bound dispatch instead of FLOPs).
+* ``"dropless"`` — one chip's share of an expert-parallel deployment
+  (``moe_apply_held``): a float32 router over all ``num_experts``, the part
+  of the result that the experts it holds give, with no token dropped, and
+  the shared experts.
+* otherwise — top-k softmax routing with capacity-bounded dispatch
+  (``moe_apply_einsum``): GShard-style one-hot dispatch/combine einsums over
+  groups of ``GROUP_SIZE`` tokens; a (token, choice) past its expert's
+  capacity in a group gets nothing from that expert. Pure pjit, shards under
+  any mesh; dispatch FLOPs overhead is ~(2/3)·T_group·cf/d_ff (≈4% for
+  dbrx, ≈1% for grok).
 
 Expert weights are stored (E, d, ff); the *sharding rule* (parallel/
 sharding.py) decides EP (experts over 'model') vs TP (d_ff over 'model').
@@ -24,7 +22,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import layers
-from repro.parallel import ctx as pctx
+
+GROUP_SIZE = 512        # tokens a capacity group, at most
 
 
 def moe_init(key, cfg):
@@ -67,13 +66,13 @@ def _expert_ffn(p, xin, cfg, dt):
     return jnp.einsum("...ecf,efd->...ecd", act * u, p["wd"].astype(dt))
 
 
-def moe_apply_einsum(p, x, cfg, *, group_size: int = 512):
+def moe_apply_einsum(p, x, cfg):
     """x: (B, S, d) -> (y, aux_loss). One-hot grouped dispatch."""
     B, S, d = x.shape
     E, k = cfg.num_experts, cfg.experts_per_token
     dt = jnp.dtype(cfg.compute_dtype)
     T = B * S
-    g = min(group_size, T)
+    g = min(GROUP_SIZE, T)
     n_grp = T // g
     x2 = x.reshape(T, d)
     w, idx, probs = _route(p, x2, cfg)
@@ -100,58 +99,18 @@ def moe_apply_einsum(p, x, cfg, *, group_size: int = 512):
     comb = jnp.sum(slot * wg_[..., None, None].astype(dt), axis=2)
 
     xin = jnp.einsum("ngec,ngd->necd", disp, xg.astype(dt))     # (n,E,cap,d)
-    # §Perf (moe_token_local): pin the dispatched/combined buffers to the
-    # token sharding. Without this the SPMD partitioner resolves the
-    # dispatch einsums by replicating expert-sized intermediates and
-    # gathering/reducing full (E, d, ff)-scale tensors once per layer per
-    # microbatch ("involuntary full rematerialization"); with it, expert
-    # weights stay sharded and only token-sized activations move.
-    xin = pctx.constrain(xin, "moe_tokens")
     yout = _expert_ffn(p, xin, cfg, dt)                          # (n,E,cap,d)
-    yout = pctx.constrain(yout, "moe_tokens")
     y = jnp.einsum("ngec,necd->ngd", comb, yout)
     return y.reshape(B, S, d).astype(x.dtype), aux
 
 
-def moe_apply_scatter(p, x, cfg):
-    """Sort/one-hot-free dispatch via scatter-add into capacity buffers."""
-    B, S, d = x.shape
-    E, k = cfg.num_experts, cfg.experts_per_token
-    dt = jnp.dtype(cfg.compute_dtype)
-    T = B * S
-    x2 = x.reshape(T, d)
-    w, idx, probs = _route(p, x2, cfg)
-    aux = aux_load_balance_loss(probs, idx, E)
-
-    cap = max(1, int(T * k / E * cfg.capacity_factor))
-    flat_e = idx.reshape(-1)                                    # (T*k,)
-    onehot_pos = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)
-    pos = (jnp.cumsum(onehot_pos, axis=0) - 1)[jnp.arange(T * k), flat_e]
-    keep = pos < cap
-    safe_pos = jnp.where(keep, pos, cap)                        # overflow slot
-
-    tok = jnp.repeat(jnp.arange(T), k)
-    buf = jnp.zeros((E, cap + 1, d), dt)
-    buf = buf.at[flat_e, safe_pos].add(x2[tok].astype(dt))
-    yout = _expert_ffn(p, buf[:, :cap][None], cfg, dt)[0]       # (E,cap,d)
-    yout = jnp.concatenate([yout, jnp.zeros((E, 1, d), dt)], axis=1)
-    gathered = yout[flat_e, safe_pos]                           # (T*k, d)
-    gathered = jnp.where(keep[:, None], gathered, 0)
-    y = jnp.zeros((T, d), jnp.float32).at[tok].add(
-        gathered.astype(jnp.float32) * w.reshape(-1)[:, None])
-    return y.reshape(B, S, d).astype(x.dtype), aux
-
-
-def moe_apply(p, x, cfg, *, impl: str = "einsum", group_size: int = 512):
+def moe_apply(p, x, cfg):
     """x: (B, S, d) -> (y, aux). A dropless layer's ``aux`` is its routed
-    counts (``moe_apply_held``)."""
+    counts (``moe_apply_held``), a capacity layer's its load-balance
+    loss."""
     if cfg.moe_dispatch == "dropless":
         return moe_apply_held(p, x, cfg)
-    if impl == "einsum":
-        return moe_apply_einsum(p, x, cfg, group_size=group_size)
-    if impl == "scatter":
-        return moe_apply_scatter(p, x, cfg)
-    raise ValueError(impl)
+    return moe_apply_einsum(p, x, cfg)
 
 
 # ---------------------------------------------------------------------------

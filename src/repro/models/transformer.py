@@ -1,7 +1,10 @@
 """LM-family model assembly: dense / MoE / VLM / hybrid(Zamba2) / SSM(RWKV6).
 
 All stacks scan over layers (params carry a leading L dim) with a
-configurable remat policy; decode threads per-layer caches through the scan.
+configurable remat policy. Decode carries every layer's attention cache
+through the layer scan and writes each layer's new position in place
+(``attention.attn_apply`` with ``cache_layer``); recurrent states are
+scanned as xs/ys.
 
 Public API (used by launch/, train/, serve/):
     init_lm(cfg, key)                       -> params
@@ -52,7 +55,7 @@ def _block_init(key, cfg, dense: bool = False):
 
 
 def _block_apply(p, x, cfg, *, positions, kv=None, cache_index=None,
-                 cache_layer=None, attn_fn=None, moe_impl="einsum"):
+                 cache_layer=None, attn_fn=None):
     """-> (x, new_kv, aux): ``aux`` is an MoE layer's load-balance loss,
     or a dropless one's routed counts (``moe.moe_apply_held``)."""
     h = layers.apply_norm(cfg.norm, p["attn_norm"], x)
@@ -62,7 +65,7 @@ def _block_apply(p, x, cfg, *, positions, kv=None, cache_index=None,
     x = x + h
     h = layers.apply_norm(cfg.norm, p["mlp_norm"], x)
     if "moe" in p:
-        h, aux = moe.moe_apply(p["moe"], h, cfg, impl=moe_impl)
+        h, aux = moe.moe_apply(p["moe"], h, cfg)
     else:
         h, aux = layers.apply_mlp(cfg.mlp, p["mlp"], h), 0.0
     return x + h, new_kv, aux
@@ -200,8 +203,7 @@ def _scan_blocks(cfg, body, x, xs, remat: str):
 
 
 def apply_lm(cfg, params, tokens, *, embeds=None, attn_fn=None,
-             remat: str = "full", moe_impl: str = "einsum",
-             collect_kv: bool = False):
+             remat: str = "full", collect_kv: bool = False):
     """Training/prefill forward. Returns (hidden, aux, kv_stack|None)."""
     x = _embed_inputs(cfg, params, tokens, embeds)
     B, S, _ = x.shape
@@ -211,7 +213,7 @@ def apply_lm(cfg, params, tokens, *, embeds=None, attn_fn=None,
         def body(carry, blk):
             h, aux = carry
             h, kv, a = _block_apply(blk, h, cfg, positions=positions,
-                                    attn_fn=attn_fn, moe_impl=moe_impl)
+                                    attn_fn=attn_fn)
             if cfg.moe_dispatch == "dropless":
                 a = 0.0                 # routed counts; no balance loss
             return (pctx.constrain(h), aux + a), (kv if collect_kv else None)
@@ -351,15 +353,18 @@ def decode_lm(cfg, params, cache, tokens):
         x, pro_sts = jax.lax.scan(mbody, x, (params["prologue"], cache["prologue"]))
         shared = params["shared_attn"]
 
-        def gbody(h, inp):
-            blk, msts, k, v = inp
+        # the groups' attention caches ride in the carry, as the dense
+        # decode's do; the mamba states are small and stay xs/ys
+        def gbody(carry, inp):
+            h, kv, g = carry
+            blk, msts = inp
             h, msts2 = jax.lax.scan(mbody, h, (blk, msts))
-            h, (k2, v2), _ = _block_apply(shared, h, cfg, positions=positions,
-                                          kv=(k, v), cache_index=idx)
-            return pctx.constrain(h, "residual_dec"), (msts2, k2, v2)
-        x, (gsts, ks, vs) = jax.lax.scan(
-            gbody, x, (params["groups"], cache["groups"],
-                       cache["attn_k"], cache["attn_v"]))
+            h, kv, _ = _block_apply(shared, h, cfg, positions=positions,
+                                    kv=kv, cache_index=idx, cache_layer=g)
+            return (pctx.constrain(h, "residual_dec"), kv, g + 1), msts2
+        (x, (ks, vs), _), gsts = jax.lax.scan(
+            gbody, (x, (cache["attn_k"], cache["attn_v"]), jnp.int32(0)),
+            (params["groups"], cache["groups"]))
         new_cache = dict(cache, prologue=pro_sts, groups=gsts,
                          attn_k=ks, attn_v=vs, idx=idx + 1)
     else:

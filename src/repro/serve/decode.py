@@ -1,12 +1,12 @@
 """Serve steps: prefill and single-token decode, jit-ready.
 
-``make_serve_step`` returns the decode_step lowered in the dry-run for the
-``decode_32k`` / ``long_500k`` cells: one new token per sequence against a
-resident KV cache (or SSM state), greedy-sampled.
+``make_prefill_step`` returns the prefill the serving engine compiles once
+per engine: the prompt's cache and its first greedy token.
+``make_serve_step`` returns the decode step: one new token per sequence
+against a resident KV cache (or SSM state), greedy-sampled. The dry-run
+(``launch/specs.py``) lowers the same two functions.
 """
 from __future__ import annotations
-
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -16,22 +16,9 @@ from jax.experimental.layout import Format, Layout
 from repro.models import api
 
 
-def make_prefill_step(cfg, *, max_seq: int, remat: str = "full",
-                      attn_chunk: int = 512, cast_params: str = "none",
-                      attn_pv_bf16: bool = False):
+def make_prefill_step(cfg, *, max_seq: int):
     def prefill_step(params, batch):
-        if cast_params != "none":
-            cdt = jnp.dtype(cast_params)
-            params = jax.tree.map(
-                lambda p: p.astype(cdt)
-                if jnp.issubdtype(p.dtype, jnp.floating) else p, params)
-        attn_fn = None
-        if attn_chunk != 512 or attn_pv_bf16:
-            from repro.models.attention import flash_ref
-            attn_fn = partial(flash_ref, chunk=attn_chunk,
-                              pv_bf16=attn_pv_bf16)
-        hidden, cache = api.prefill(cfg, params, batch, max_seq=max_seq,
-                                    remat=remat, attn_fn=attn_fn)
+        hidden, cache = api.prefill(cfg, params, batch, max_seq=max_seq)
         logits = api.unembed(cfg, params, hidden[:, -1:])
         next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return next_tok, cache

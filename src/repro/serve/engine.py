@@ -1,10 +1,10 @@
 """Batched serving engine: continuous-batching-lite over jitted prefill /
-decode steps, with straggler deadlines driven by the SimFA performance
-predictor (the paper's model as a production feature — DESIGN.md §4).
+decode steps, with a step-time watchdog (``StragglerPolicy``) whose
+deadline the caller sets.
 
 Slots hold independent requests; finished slots are refilled from the queue
-without stopping the decode loop. Designed so the decode step is the same
-function the dry-run lowers for the decode_32k/long_500k cells. The prefill
+without stopping the decode loop. The decode step is the function the
+dry-run lowers (``serve.decode.make_serve_step``). The prefill
 and unembed of one request are one program too (``make_prefill_step``),
 compiled at the engine's first admission for its fixed prompt length and
 only dispatched after that; ``ServeEngine.prefill_compiles`` counts the
@@ -67,9 +67,10 @@ class Request:
 
 @dataclass
 class StragglerPolicy:
-    """Deadline-based step watchdog: expected step time comes from the
-    SimFA predictor; steps slower than ``factor`` x expectation are counted
-    and surfaced (on real fleets: triggers re-dispatch / hot-spare swap)."""
+    """Deadline-based step watchdog: the caller gives the expected step
+    time (or ``from_samples`` sets it from sampled step times); steps
+    slower than ``factor`` x expectation are counted and surfaced (on real
+    fleets: triggers re-dispatch / hot-spare swap)."""
     expected_step_s: float = 0.1
     factor: float = 5.0
     slow_steps: int = 0

@@ -25,16 +25,10 @@ from repro.train.loss import chunked_cross_entropy
 class RunConfig:
     microbatches: int = 1
     remat: str = "full"
-    moe_impl: str = "einsum"
     moe_aux_weight: float = 0.01
     loss_chunk: int = 2048
     grad_dtype: str = "float32"        # gradient accumulator dtype
     grad_compress: str = "none"        # none | int8 (error-feedback)
-    cast_params: str = "none"          # none | bfloat16: fwd/bwd compute
-                                       # params (fp32 masters kept in state;
-                                       # FSDP all-gathers move bf16 — §Perf)
-    attn_chunk: int = 512              # flash_ref KV chunk (§Perf knob)
-    attn_pv_bf16: bool = False         # FA3-style P-tile cast (§Perf knob)
     opt: opt.OptConfig = opt.OptConfig()
 
 
@@ -63,22 +57,8 @@ def _quantize_int8(g, err):
 
 
 def make_loss_fn(cfg, run: RunConfig):
-    from functools import partial as _partial
-    from repro.models.attention import flash_ref
-
-    attn_fn = (None if run.attn_chunk == 512 and not run.attn_pv_bf16
-               else _partial(flash_ref, chunk=run.attn_chunk,
-                             pv_bf16=run.attn_pv_bf16))
-
     def loss_fn(params, mb):
-        if run.cast_params != "none":
-            cdt = jnp.dtype(run.cast_params)
-            params = jax.tree.map(
-                lambda p: p.astype(cdt)
-                if jnp.issubdtype(p.dtype, jnp.floating) else p, params)
-        hidden, aux = api.forward_hidden(
-            cfg, params, mb, remat=run.remat, moe_impl=run.moe_impl,
-            attn_fn=attn_fn)
+        hidden, aux = api.forward_hidden(cfg, params, mb, remat=run.remat)
         s_tok = mb["labels"].shape[1]
         loss, w = chunked_cross_entropy(
             hidden[:, -s_tok:], api.unembed_table(cfg, params), mb["labels"],
@@ -93,7 +73,7 @@ def make_train_step(cfg, run: RunConfig, grad_specs=None):
     Constraining the gradient accumulator to the parameter sharding turns
     the per-microbatch gradient reduction into a reduce-scatter onto the
     FSDP shards instead of a full all-reduce of replicated gradients
-    (§Perf: 2x wire bytes + no replicated accumulator in HBM)."""
+    (half the wire bytes, and no replicated accumulator in HBM)."""
     loss_fn = make_loss_fn(cfg, run)
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 

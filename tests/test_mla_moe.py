@@ -5,7 +5,8 @@ experts against the uncut layer, no token dropped at any load, prefill and
 cached decode (through the serving engine too) against the plain
 reference, the parameter count against the benchmark's counts, the
 sharding rules, and the engine's splice refusing a cache it cannot
-place."""
+place. Beside the dropless layer, the capacity-bounded one (reduced
+dbrx-132b) against a token-by-token reference."""
 from __future__ import annotations
 
 import copy
@@ -26,6 +27,7 @@ if str(ROOT) not in sys.path:
 from chipbench import counts_mla_moe  # noqa: E402
 from chipbench.drivers.serve_closed_mla_moe import program_config  # noqa: E402
 from chipbench.reference import mla_moe_lm  # noqa: E402
+from repro.configs import registry  # noqa: E402
 from repro.launch.serve import init_params  # noqa: E402
 from repro.models import api, attention, layers, moe  # noqa: E402
 from repro.parallel import sharding  # noqa: E402
@@ -126,6 +128,55 @@ def test_no_token_is_dropped_at_any_load():
                                rtol=0)
     assert counts[2] == counts[5] == counts[11] == 64
     assert int(counts.sum()) == 64 * 3
+
+
+def _capacity_reference(p, x, cfg, cap):
+    """Token by token, in float64: the softmax router's top k, their
+    weights renormalised, each chosen expert's SwiGLU weighted by its
+    share; a choice of an expert that has already served ``cap`` (token,
+    choice) pairs of the token's group of ``moe.GROUP_SIZE`` gives
+    nothing."""
+    x2 = np.asarray(x, np.float64).reshape(-1, cfg.d_model)
+    logits = x2 @ np.asarray(p["router"], np.float64)
+    probs = np.exp(logits - logits.max(-1, keepdims=True))
+    probs /= probs.sum(-1, keepdims=True)
+    wg, wu, wd = (np.asarray(p[k], np.float64) for k in ("wg", "wu", "wd"))
+    y = np.zeros_like(x2)
+    for t, xt in enumerate(x2):
+        if t % moe.GROUP_SIZE == 0:
+            served = np.zeros(cfg.num_experts, int)
+        top = np.argsort(-probs[t])[:cfg.experts_per_token]
+        for e, w in zip(top, probs[t, top] / probs[t, top].sum()):
+            if served[e] < cap:
+                g, u = xt @ wg[e], xt @ wu[e]
+                y[t] += w * ((g / (1 + np.exp(-g))) * u) @ wd[e]
+            served[e] += 1
+    return y.reshape(x.shape)
+
+
+@pytest.mark.parametrize("cap", [moe.GROUP_SIZE, 1], ids=["ample", "one"])
+def test_capacity_layer_matches_a_per_token_reference(cap):
+    """Two groups of tokens through the capacity-bounded layer: with room
+    for every token an expert could get, it is the plain top-k mixture;
+    with room for one a group, each expert serves only the first token of
+    each group that picked it, and later choices of it give nothing."""
+    base = registry.get("dbrx-132b").reduced()
+    E, k = base.num_experts, base.experts_per_token
+    # moe_apply_einsum's capacity: max(1, int(group * k / E * factor))
+    factor = cap * E / (moe.GROUP_SIZE * k)
+    cfg = dataclasses.replace(base, compute_dtype="float32",
+                              capacity_factor=factor)
+    p = moe.moe_init(KEY, cfg)
+    x = jax.random.normal(jax.random.PRNGKey(1),
+                          (2, moe.GROUP_SIZE, cfg.d_model))
+    y, _ = moe.moe_apply(p, x, cfg)
+    want = _capacity_reference(p, x, cfg, cap)
+    np.testing.assert_allclose(np.asarray(y), want, atol=1e-5, rtol=1e-5)
+    served = np.any(want != 0, axis=-1).sum()
+    if cap == 1:            # at most E tokens of each group get anything
+        assert 0 < served <= 2 * E
+    else:
+        assert served == 2 * moe.GROUP_SIZE
 
 
 def _program(conf):

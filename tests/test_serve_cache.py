@@ -5,10 +5,12 @@ slots; with the cache's axes stored in the order the compiler chose here
 (the logical one on the CPU) and in the order a v5e chooses. Its prefill
 is one program, built at the first admission, that later admissions only
 dispatch, and gives the eager prefill's first token and cache (dense and
-latent caches)."""
+latent caches). The hybrid and encoder-decoder decode steps write their
+stacked K/V caches one position per layer as well."""
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -86,17 +88,59 @@ def test_decode_step_writes_one_position_per_layer(arch, axes):
         np.testing.assert_array_equal(after[name][:, :, others],
                                       before[name][:, :, others])
 
-    # layer by layer, un-jitted, each layer's own cache
+    # layer by layer, un-jitted, outside the scan: each layer written into
+    # the cache as it stood before the step
     x = transformer._embed_inputs(cfg, params, jnp.asarray(tokens), None)
     positions = jnp.full((1, 1), idx, jnp.int32)
     for layer in range(cfg.num_layers):
         blk = jax.tree.map(lambda p: p[layer], params["blocks"])
         x, (k, v), _ = transformer._block_apply(
             blk, x, cfg, positions=positions, cache_index=idx,
-            kv=(before["k"][layer], before["v"][layer]))
-        np.testing.assert_allclose(after["k"][layer, :, idx], k[:, idx],
-                                   rtol=1e-5, atol=1e-5)
-        np.testing.assert_allclose(after["v"][layer, :, idx], v[:, idx],
+            kv=(before["k"], before["v"]), cache_layer=layer)
+        np.testing.assert_allclose(after["k"][layer, :, idx],
+                                   k[layer, :, idx], rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(after["v"][layer, :, idx],
+                                   v[layer, :, idx], rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("arch", ["zamba2-7b", "whisper-large-v3"],
+                         ids=["hybrid", "encdec"])
+def test_hybrid_and_encdec_decode_write_one_position_per_layer(arch):
+    """A hybrid's shared-attention groups and an encoder-decoder's decoder
+    layers write their stacked K/V cache in place too: two donated decode
+    steps after a prefill, each giving a cache-free forward's logits and
+    changing no cache position but the one it writes."""
+    base = registry.get(arch).reduced()
+    # three attention layers, so that a layer written one index off lands
+    # on another layer's K/V
+    more = (dict(hybrid_groups=3) if base.family == "hybrid"
+            else dict(dec_layers=3))
+    cfg = dataclasses.replace(base, compute_dtype="float32", **more)
+    params = api.init(cfg, KEY)
+    rng = np.random.default_rng(1)
+    toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (2, PROMPT + 2)),
+                       jnp.int32)
+    batch = {"tokens": toks[:, :PROMPT]}
+    if cfg.family == "encdec":
+        batch["frames"] = 0.1 * jax.random.normal(
+            KEY, (2, cfg.frontend_len, cfg.d_model))
+    _, cache = api.prefill(cfg, params, batch, max_seq=MAX_SEQ)
+    step = jax.jit(functools.partial(api.decode, cfg), donate_argnums=(1,))
+    names = ("attn_k", "attn_v") if cfg.family == "hybrid" else ("k", "v")
+    for idx in (PROMPT, PROMPT + 1):
+        before = {n: np.array(cache[n]) for n in names}
+        logits, cache = step(params, cache, toks[:, idx:idx + 1])
+        assert int(cache["idx"]) == idx + 1
+        others = np.arange(MAX_SEQ) != idx
+        for n in names:
+            after = np.asarray(cache[n])
+            np.testing.assert_array_equal(after[:, :, others],
+                                          before[n][:, :, others])
+            assert np.all(after[:, :, idx] != 0)
+        hidden, _ = api.forward_hidden(
+            cfg, params, dict(batch, tokens=toks[:, :idx + 1]), remat="none")
+        want = api.unembed(cfg, params, hidden[:, -1:])
+        np.testing.assert_allclose(np.asarray(logits), np.asarray(want),
                                    rtol=1e-5, atol=1e-5)
 
 
