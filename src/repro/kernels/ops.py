@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from repro.kernels import flash_attention as _fa
 from repro.kernels import flash_decode as _fd
+from repro.kernels import mla_decode as _md
 from repro.models import attention as _attn
 
 
@@ -65,3 +66,17 @@ def decode_forward(q, k_cache, v_cache, cache_len, *, block_k: int = 512,
     if return_partials:
         return out
     return out.reshape(B, 1, H, D)
+
+
+def mla_decode_forward(q_c, q_pe, c_kv, k_pe, layer, cache_len, *,
+                       scale: float, mode: Optional[str] = None):
+    """Absorbed latent decode attention over layer ``layer`` of every
+    layer's cache. Layout: q_c (B, H, r), q_pe (B, H, rope); c_kv (L, B, S,
+    r), k_pe (L, B, S, rope) -> (B, H, r) float32. The model path picks the
+    kernel by backend itself (``attention.mla_attend_absorbed``)."""
+    mode = _resolve(mode)
+    if mode == "reference":
+        return _attn.mla_latent_attend(q_c, q_pe, c_kv, k_pe, layer,
+                                       cache_len, scale=scale)
+    return _md.mla_decode(q_c, q_pe, c_kv, k_pe, layer, cache_len,
+                          scale=scale, interpret=(mode == "interpret"))

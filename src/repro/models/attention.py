@@ -4,15 +4,18 @@
 FlashAttention, scanned over KV chunks): the attention of every family's
 training forward and prefill unless a caller passes ``attn_fn``, and the
 oracle for the Pallas kernels in ``repro.kernels``. Decode attends over the
-cache with ``decode_attend`` (or ``mla_attend_absorbed``).
+cache with ``decode_attend`` (or ``mla_attend_absorbed``, whose core runs
+the ``kernels.mla_decode`` kernel where the program is lowered for a TPU).
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import mla_decode
 from repro.models import layers
 from repro.parallel import ctx as pctx
 
@@ -291,31 +294,57 @@ def mla_attend_expanded(p, q_nope, q_pe, latent, cfg, *, attn_fn=None):
     return o.reshape(B, T, H * cfg.v_head_dim)
 
 
-def mla_attend_absorbed(p, q_nope, q_pe, latent, cache_len, cfg):
-    """One query token a sequence over the first ``cache_len`` positions of
-    ``latent`` (c_kv (B, S_max, r), k_pe (B, S_max, rope)), absorbed: bf16
-    (compute-dtype) operands, float32 accumulation. -> (B, 1, H * v)."""
-    dt, f32 = jnp.dtype(cfg.compute_dtype), jnp.float32
-    c, k_pe = (a.astype(dt) for a in latent)
+def mla_latent_attend(q_c, q_pe, c_kv, k_pe, layer, cache_len, *, scale):
+    """The absorbed decode's attention core in jnp: the plain form of
+    ``kernels.mla_decode``, and its path off the TPU. q_c (B, H, r), q_pe
+    (B, H, rope) in the compute dtype; c_kv (L, B, S_max, r), k_pe (L, B,
+    S_max, rope): every layer's cache. Slices out layer ``layer``, scores
+    all ``S_max`` positions and masks those at or past ``cache_len``. ->
+    (B, H, r) float32, the softmax-weighted sum of that layer's c_kv."""
+    dt, f32 = q_c.dtype, jnp.float32
+    c, pe = (jax.lax.dynamic_index_in_dim(a, layer, keepdims=False).astype(dt)
+             for a in (c_kv, k_pe))
     B, S, _ = c.shape
-    H, nope = cfg.num_heads, cfg.qk_nope_head_dim
-    w = _mla_wkv_b(p, cfg)
-    q_c = jnp.einsum("bhn,chn->bhc", q_nope[:, 0].astype(dt), w[..., :nope],
-                     preferred_element_type=f32).astype(dt)
     s = jnp.einsum("bhc,bsc->bhs", q_c, c, preferred_element_type=f32) \
-        + jnp.einsum("bhr,bsr->bhs", q_pe[:, 0].astype(dt), k_pe,
-                     preferred_element_type=f32)
-    s = s * (1.0 / math.sqrt(nope + cfg.qk_rope_head_dim))
+        + jnp.einsum("bhr,bsr->bhs", q_pe, pe, preferred_element_type=f32)
+    s = s * scale
     valid = jnp.arange(S)[None, :] < jnp.broadcast_to(
         jnp.asarray(cache_len), (B,))[:, None]
     s = jnp.where(valid[:, None, :], s, NEG_INF)
     pr = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
     o_c = jnp.einsum("bhs,bsc->bhc", pr.astype(dt), c,
                      preferred_element_type=f32)
-    o_c = o_c / jnp.sum(pr, axis=-1)[..., None]
+    return o_c / jnp.sum(pr, axis=-1)[..., None]
+
+
+def _latent_attend(q_c, q_pe, c_kv, k_pe, layer, cache_len, *, scale):
+    """:func:`mla_latent_attend`, run by the Pallas kernel where the
+    program is lowered for a TPU and the kernel tiles the shapes."""
+    core = functools.partial(mla_latent_attend, scale=scale)
+    if not mla_decode.tileable(q_c, q_pe, c_kv, k_pe):
+        return core(q_c, q_pe, c_kv, k_pe, layer, cache_len)
+    return jax.lax.platform_dependent(
+        q_c, q_pe, c_kv, k_pe, layer, cache_len,
+        tpu=functools.partial(mla_decode.mla_decode, scale=scale),
+        default=core)
+
+
+def mla_attend_absorbed(p, q_nope, q_pe, latent, layer, cache_len, cfg):
+    """One query token a sequence over layer ``layer`` of ``latent`` (every
+    layer's c_kv (L, B, S_max, r) and k_pe (L, B, S_max, rope)), positions
+    at or past ``cache_len`` masked, absorbed: compute-dtype operands,
+    float32 accumulation. -> (B, 1, H * v)."""
+    dt, f32 = jnp.dtype(cfg.compute_dtype), jnp.float32
+    nope = cfg.qk_nope_head_dim
+    w = _mla_wkv_b(p, cfg)
+    q_c = jnp.einsum("bhn,chn->bhc", q_nope[:, 0].astype(dt), w[..., :nope],
+                     preferred_element_type=f32).astype(dt)
+    o_c = _latent_attend(q_c, q_pe[:, 0].astype(dt), *latent, layer,
+                         cache_len,
+                         scale=1.0 / math.sqrt(nope + cfg.qk_rope_head_dim))
     o = jnp.einsum("bhc,chv->bhv", o_c.astype(dt), w[..., nope:],
                    preferred_element_type=f32)
-    return o.reshape(B, 1, H * cfg.v_head_dim).astype(dt)
+    return o.reshape(q_nope.shape[0], 1, -1).astype(dt)
 
 
 def mla_apply(p, x, cfg, *, positions, kv_cache=None, cache_index=None,
@@ -335,8 +364,7 @@ def mla_apply(p, x, cfg, *, positions, kv_cache=None, cache_index=None,
     cache = tuple(jax.lax.dynamic_update_slice(
         a, new[None].astype(a.dtype), (cache_layer, 0, cache_index, 0))
         for a, new in zip(kv_cache, lat))
-    here = tuple(jax.lax.dynamic_index_in_dim(a, cache_layer, keepdims=False)
-                 for a in cache)
     with jax.named_scope("mla.attend"):
-        o = mla_attend_absorbed(p, q_nope, q_pe, here, cache_index + T, cfg)
+        o = mla_attend_absorbed(p, q_nope, q_pe, cache, cache_layer,
+                                cache_index + T, cfg)
     return layers.dense(p["wo"], o, dtype=dt), cache

@@ -7,7 +7,7 @@ import pytest
 pytest.importorskip("hypothesis")  # optional dev dep: degrade, don't die
 from hypothesis import given, settings, strategies as st
 
-from repro.kernels import ops, ref
+from repro.kernels import mla_decode, ops, ref
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.flash_decode import flash_decode
 from repro.models import attention
@@ -63,6 +63,46 @@ def test_flash_decode_matches_ref(B, H, Hkv, S, D, clen, partials):
         o = flash_decode(q, kc, vc, clen, block_k=128, interpret=True)
     np.testing.assert_allclose(np.asarray(o, np.float32),
                                np.asarray(o_ref, np.float32), atol=2e-5, rtol=2e-5)
+
+
+# moonlight-16b-a3b's latent widths; the kernel's block is 512 positions
+MLA_H, MLA_R, MLA_ROPE, MLA_B, MLA_S, MLA_BS = 16, 512, 64, 4, 1024, 512
+
+
+@pytest.mark.parametrize("clen", [1, MLA_BS - 1, MLA_BS, 700, MLA_S])
+def test_mla_decode_matches_the_jnp_core(clen):
+    """The latent decode kernel, reading layer 2 of a stacked (L, B, S, .)
+    cache, equals the jnp core (bf16 operands, float32 accumulation) at
+    every fill, block edges included; values past the fill change nothing
+    (their blocks are neither used nor needed); and on the CPU the model
+    path's dispatch runs the jnp core itself (elsewhere, the kernel)."""
+    ks = jax.random.split(KEY, 5)
+    dt = jnp.bfloat16
+    q_c = jax.random.normal(ks[0], (MLA_B, MLA_H, MLA_R), dt)
+    q_pe = jax.random.normal(ks[1], (MLA_B, MLA_H, MLA_ROPE), dt)
+    c_kv = jax.random.normal(ks[2], (3, MLA_B, MLA_S, MLA_R), dt)
+    k_pe = jax.random.normal(ks[3], (3, MLA_B, MLA_S, MLA_ROPE), dt)
+    args = (q_c, q_pe, c_kv, k_pe, 2, clen)
+    scale = 1.0 / np.sqrt(128 + MLA_ROPE)
+    assert mla_decode.blocks(MLA_B, MLA_S, MLA_R, 2)[1] == MLA_BS
+    want = ops.mla_decode_forward(*args, scale=scale, mode="reference")
+    got = ops.mla_decode_forward(*args, scale=scale, mode="interpret")
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               **_tol(dt))
+    past = jnp.arange(MLA_S)[None, None, :, None] >= clen
+    noise = [100.0 * jax.random.normal(k, a.shape, dt)
+             for k, a in zip(jax.random.split(ks[4]), (c_kv, k_pe))]
+    c2, pe2 = (jnp.where(past, n, a) for n, a in zip(noise, (c_kv, k_pe)))
+    again = ops.mla_decode_forward(q_c, q_pe, c2, pe2, 2, clen, scale=scale,
+                                   mode="interpret")
+    np.testing.assert_array_equal(np.asarray(again), np.asarray(got))
+    dispatched = jax.jit(lambda *a: attention._latent_attend(*a,
+                                                             scale=scale))
+    exact = jax.default_backend() != "tpu"
+    np.testing.assert_allclose(np.asarray(dispatched(*args)),
+                               np.asarray(want),
+                               **(dict(atol=0, rtol=0) if exact else _tol(dt)))
 
 
 def test_block_size_invariance():

@@ -78,7 +78,8 @@ def test_absorbed_decode_equals_the_expanded_attention():
         [a, jax.random.normal(jax.random.PRNGKey(2), (2, S - T, a.shape[-1]))],
         axis=1) for a in lat)
     got = attention.mla_attend_absorbed(p, q_nope[:, -1:], q_pe[:, -1:],
-                                        cache, T, cfg)
+                                        tuple(a[None] for a in cache), 0, T,
+                                        cfg)
     assert got.shape == (2, 1, cfg.num_heads * cfg.v_head_dim)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5,
                                rtol=0)

@@ -98,13 +98,15 @@ def _shapes(text):
             for ds in re.findall(r"\w+\[([\d,]*)\]", text)}
 
 
-def _hbm_copies(hlo, dims):
+def _hbm_copies(hlo, dims, *, staged=False):
     """Copies and fusions of the optimized HLO text ``hlo`` that write an
     array of one of the shapes ``dims`` to HBM, outside any fused
     computation, other than a fusion whose root dynamic-update-slices an
     update of none of those shapes into one of its own parameters (in
     place). A fusion into VMEM (memory space ``S(1)``) stages what its
-    consumer reads from HBM; it writes nothing there."""
+    consumer reads from HBM; it writes nothing there, and counts only
+    where ``staged``: such a staging copy is still a read of the whole
+    array and a write of it."""
     comps, name = {}, None
     for line in hlo.splitlines():
         m = re.match(r"(?:ENTRY )?%(\S+) \(", line)
@@ -135,7 +137,7 @@ def _hbm_copies(hlo, dims):
             continue
         for ln in lines:
             m = re.match(r"(?:ROOT )?%\S+ = (.+?) (copy|fusion)\(", ln)
-            if m is None or "S(1)" in m.group(1):
+            if m is None or ("S(1)" in m.group(1) and not staged):
                 continue
             if _shapes(m.group(1)) & dims and not (m.group(2) == "fusion"
                                       and in_place(ln)):
@@ -209,3 +211,39 @@ def test_latent_decode_step_updates_the_cache_in_place(one_chip):
     assert _hbm_copies(compiled.as_text(), dims) == []
     routed = compiled.out_info[2]
     assert routed.shape == (cfg.num_layers - 1, 8)
+
+
+def test_latent_decode_attention_is_one_kernel_over_the_stored_cache(
+        one_chip):
+    """moonlight-16b-a3b.long-decode-32's engine step (32 slots, 2048
+    positions): its latent decode attention is the Pallas kernel
+    ``mla_decode_attend``, which reads each layer's c_kv and k_pe where the
+    stacked cache stores them. No fusion or copy outside it writes an
+    array of one layer's latent (or of the whole stack), into HBM or
+    staged into VMEM, and the step's scratch stays under one layer's
+    latent."""
+    import dataclasses
+    cfg = dataclasses.replace(registry.get("moonlight-16b-a3b"),
+                              experts_held=8)
+    slots, max_seq = 32, 2048
+    params = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: init_params(cfg, 0)))
+    shape = dict(batch=slots, max_seq=max_seq, sharding=one_chip)
+    axes = cache_axes(cfg, params, **shape)
+    compiled = compile_serve_step(cfg, params, axes, **shape)
+    hlo = compiled.as_text()
+    kernels = re.findall(r"%mla_decode_attend\S* = .*tpu_custom_call", hlo)
+    assert len(kernels) == 2        # the leading dense layer, the scan body
+    dims = set()
+    for name, w in (("c_kv", cfg.kv_lora_rank),
+                    ("k_pe", cfg.qk_rope_head_dim)):
+        for n, arr in ((cfg.num_layers - 1, name), (1, "dense_" + name)):
+            stack = (n, slots, max_seq, w)
+            stored = tuple(stack[i] for i in axes[arr])
+            dims |= {d for s in (stack, stored)
+                     for d in (s, s[1:], (1,) + s[1:])}
+    assert _hbm_copies(hlo, dims, staged=True) == []
+    latent_bytes = 2 * slots * max_seq * (cfg.kv_lora_rank
+                                          + cfg.qk_rope_head_dim)
+    assert compiled.memory_analysis().temp_size_in_bytes < latent_bytes
